@@ -22,10 +22,3 @@ Kernels:
   decode_attn  -- flash-decode over a KV cache (single-token serving)
   rmsnorm      -- fused RMSNorm
 """
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# renamed TPUCompilerParams -> CompilerParams across jax versions; kernels
-# import this single shim instead of guarding per-module
-CompilerParams = getattr(_pltpu, "CompilerParams", None) \
-    or getattr(_pltpu, "TPUCompilerParams")

@@ -30,6 +30,7 @@ softmax differently).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib
 import math
@@ -39,9 +40,17 @@ import jax
 
 
 def use_interpret() -> bool:
-    """Interpret-mode rule shared by every registered op (was copy-pasted
-    per kernel subpackage): Pallas interprets on non-TPU backends."""
-    return jax.default_backend() != "tpu"
+    """Interpret-mode rule shared by every registered op: Mosaic kernels
+    compile on TPU and are interpreted on the CPU backend (tests, local
+    runs). Any other backend is an error, never a silent interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels cannot run on the {backend!r} backend: they "
+        f"compile for TPU and are interpreted only on CPU")
 
 
 def fit_block(value: int, extent: int) -> int:
@@ -127,6 +136,19 @@ def default_point(op: TunableOp) -> Dict[str, Any]:
     return dict(op.default)
 
 
+def _resolve(op: TunableOp, shape_key: str) -> Tuple[Dict[str, Any], str]:
+    from repro.kernels import tuned  # local: keep api import-light
+
+    point = default_point(op)
+    cached = tuned.lookup(op.name, shape_key)
+    if not cached:
+        return point, "default"
+    for axis in op.axes:
+        if axis in cached:
+            point[axis] = cached[axis]
+    return point, "tuned"
+
+
 def resolve_point(op: TunableOp, *args, **kwargs) -> Dict[str, Any]:
     """Tuned-cache lookup at op-call time, deterministic default fallback.
 
@@ -136,15 +158,33 @@ def resolve_point(op: TunableOp, *args, **kwargs) -> Dict[str, Any]:
     run. Unknown axes in a cached point (an older/newer schema) are
     dropped rather than trusted.
     """
-    from repro.kernels import tuned  # local: keep api import-light
+    return _resolve(op, op.shape_key(*args, **kwargs))[0]
 
-    point = default_point(op)
-    cached = tuned.lookup(op.name, op.shape_key(*args, **kwargs))
-    if cached:
-        for axis in op.axes:
-            if axis in cached:
-                point[axis] = cached[axis]
-    return point
+
+@dataclasses.dataclass(frozen=True)
+class Dispatch:
+    """One kernel dispatch: the clamped point it ran and where that point
+    came from ("explicit", "tuned" from the persisted cache, "default")."""
+    op: str
+    shape_key: str
+    point: Dict[str, Any]
+    source: str
+
+
+_recorders: list = []            # open record_dispatches() logs
+
+
+@contextlib.contextmanager
+def record_dispatches():
+    """Collect every kernel dispatch made inside the block, in order, as
+    :class:`Dispatch` records (``use_ref`` calls run no kernel and are
+    not recorded)."""
+    log: list = []
+    _recorders.append(log)
+    try:
+        yield log
+    finally:
+        _recorders.remove(log)
 
 
 def call(name: str, *args, point: Optional[Mapping[str, Any]] = None,
@@ -153,13 +193,16 @@ def call(name: str, *args, point: Optional[Mapping[str, Any]] = None,
     op = get_op(name)
     if use_ref:
         return op.ref(*args, **kwargs)
+    key = op.shape_key(*args, **kwargs)
     if point is None:
-        point = resolve_point(op, *args, **kwargs)
+        point, source = _resolve(op, key)
     else:
         merged = default_point(op)
         merged.update({a: v for a, v in point.items() if a in op.axes})
-        point = merged
+        point, source = merged, "explicit"
     point = op.clamp(dict(point), *args, **kwargs)
+    for log in _recorders:
+        log.append(Dispatch(name, key, dict(point), source))
     return op.run(point, *args, **kwargs)
 
 

@@ -12,19 +12,14 @@ from __future__ import annotations
 import jax
 
 
-def _auto_kw(n):
-    # jax.sharding.AxisType landed after 0.4.x; older jax has neither the
-    # enum nor the make_mesh kwarg, and Auto is its default behavior anyway.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _auto(n):
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_kw(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(model_parallel: int = 1):
@@ -32,4 +27,4 @@ def make_local_mesh(model_parallel: int = 1):
     n = jax.device_count()
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"), **_auto_kw(2))
+                         ("data", "model"), axis_types=_auto(2))

@@ -79,6 +79,7 @@ import numpy as np
 from repro.configs import get_config, smoke_config
 from repro.dist import collectives, fanin
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import registry, transformer
 from repro.train import step as step_lib
@@ -276,7 +277,8 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
              stream: str = "batch", slots: int = 0,
              workers: int = 1, evict: str = "oldest", paged: bool = False,
              page_size: int = 0, pool_pages: int = 0, horizon: int = 0,
-             priorities: Optional[np.ndarray] = None, prefill_meshes=None):
+             priorities: Optional[np.ndarray] = None, prefill_meshes=None,
+             keep_logits: bool = False):
     """prompts: (B, S0) int32, right-padded when ragged. Greedy (or
     sampled) decode of ``max_new`` tokens per row.
 
@@ -313,11 +315,19 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
     ``horizon`` caps the decode horizon in positions (0 = sized to fit):
     an unpaged request that cannot fit is refused loudly, never silently
     truncated; a paged one admits.
+
+    ``keep_logits=True`` (batch and slot streams) keeps the logit row each
+    generated token was chosen from, on the device until the call
+    returns, and leaves them as ``last_stats["logits"]``, float32
+    ``(B, max_new, vocab)``: what a caller checks the serving numerics
+    against. Off, the loop keeps nothing.
     """
     if stream not in STREAMS:
         raise ValueError(f"unknown stream {stream!r}; "
                          f"expected one of {STREAMS}")
     if workers > 1 or paged or prefill_meshes is not None:
+        if keep_logits:
+            raise ValueError("keep_logits: batch and slot streams only")
         return _generate_fanin(
             cfg, params, prompts, max_new=max_new, temperature=temperature,
             seed=seed, prompt_lens=prompt_lens, mesh=mesh, rules=rules,
@@ -333,7 +343,8 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
             seed=seed, prompt_lens=prompt_lens, mesh=mesh, rules=rules,
             act_transport=act_transport, decode_mesh=decode_mesh,
             decode_rules=decode_rules, cache_transfer=cache_transfer,
-            kv_storage=kv_storage, slots=slots, horizon=horizon)
+            kv_storage=kv_storage, slots=slots, horizon=horizon,
+            keep_logits=keep_logits)
     b, s0 = prompts.shape
     total = s0 + max_new
     ragged = prompt_lens is not None
@@ -433,10 +444,13 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
         # tensor that crosses from the prefill to the decode mesh
         key = jax.random.PRNGKey(seed)
         out_tokens = []
+        kept = []
         tok = jnp.asarray(np.asarray(jnp.argmax(logits, -1),
                                      dtype=np.int32)[:, None])
         for i in range(max_new):
             out_tokens.append(np.asarray(tok))
+            if keep_logits:
+                kept.append(logits)
             pos = jnp.asarray(lens + i) if ragged \
                 else jnp.asarray(s0 + i, jnp.int32)
             logits, cache = decode(params_dec, cache,
@@ -447,6 +461,9 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
                                              ).astype(jnp.int32)[:, None]
             else:
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+    generate.last_stats = {"logits": np.stack(
+        [np.asarray(x, np.float32) for x in kept], axis=1)
+        if keep_logits else None}
     return np.concatenate(out_tokens, axis=1)
 
 
@@ -500,7 +517,7 @@ def _generate_slots(cfg, params, prompts: np.ndarray, max_new: int,
                     mesh, rules, act_transport: str,
                     decode_mesh, decode_rules,
                     cache_transfer: str, kv_storage: str, slots: int,
-                    horizon: int = 0):
+                    horizon: int = 0, keep_logits: bool = False):
     """Continuous cross-batch disaggregation: prefill streams each
     finished request's cache slice into a RUNNING decode batch.
 
@@ -519,7 +536,8 @@ StateStore` row write), and the slot decodes from the request's own
     is dispatched (async) at admission time, so it overlaps the decode
     steps that run before the next slot frees; the wall-clock wait the
     overlap failed to hide is recorded in ``_generate_slots.last_stats``
-    (the launcher prints it).
+    (the launcher prints it), with the kept logit rows under
+    ``keep_logits`` (see :func:`generate`).
 
     Returns tokens ``(B, max_new)``; greedy tokens are token-for-token
     identical to the whole-batch path (per-row attention independence —
@@ -616,6 +634,8 @@ StateStore` row write), and the slot decodes from the request's own
     next_req = 0
     inflight: list = []                # at most one prefetched shipment
     stats = {"admissions": 0, "transfer_wait_s": 0.0, "decode_steps": 0}
+    # per request, the (logits, row) each of its tokens was chosen from
+    kept = [[] for _ in range(b)] if keep_logits else None
 
     def start_prefetch():
         """Prefill + ship the next pending request (async dispatch): the
@@ -642,7 +662,7 @@ StateStore` row write), and the slot decodes from the request's own
             tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
         if mover is not None:
             slc = mover(slc)
-        inflight.append((i, slc, tok0))
+        inflight.append((i, slc, tok0, logits))
 
     def emit(i, t, slot):
         out_tokens[i].append(int(t))
@@ -653,7 +673,7 @@ StateStore` row write), and the slot decodes from the request's own
         nonlocal cache
         if not inflight:
             start_prefetch()
-        i, slc, tok0 = inflight.pop(0)
+        i, slc, tok0, logits0 = inflight.pop(0)
         t0 = time.time()
         jax.block_until_ready(slc)     # what the overlap failed to hide
         stats["transfer_wait_s"] += time.time() - t0
@@ -664,6 +684,8 @@ StateStore` row write), and the slot decodes from the request's own
         slot_pos[slot] = lens[i]
         slot_tok[slot] = int(np.asarray(tok0)[0])
         slot_keys[slot] = jax.random.fold_in(key, i)
+        if kept is not None:
+            kept[i].append((logits0, 0))
         emit(i, slot_tok[slot], slot)  # the prefill token
         start_prefetch()               # double buffer the next shipment
 
@@ -705,9 +727,20 @@ StateStore` row write), and the slot decodes from the request's own
                 continue
             slot_tok[s_] = nxt[s_]
             slot_pos[s_] += 1
+            if kept is not None:
+                kept[i].append((logits, s_))
             emit(i, nxt[s_], s_)
 
     assert all(len(ts) == max_new for ts in out_tokens)
+    if kept is not None:
+        host = {}                      # one device-to-host copy per array
+
+        def row(x, r):
+            if id(x) not in host:
+                host[id(x)] = np.asarray(x, np.float32)
+            return host[id(x)][r]
+        kept = np.stack([[row(x, r) for x, r in rows] for rows in kept])
+    stats["logits"] = kept
     _generate_slots.last_stats = stats     # launcher reporting hook
     return np.asarray(out_tokens, np.int32)
 
@@ -1616,8 +1649,13 @@ def resolve_config(args):
     return get_config(args.arch) if args.full else smoke_config(args.arch)
 
 
-def main(argv=None) -> None:
-    args = build_parser().parse_args(argv)
+def setup(args):
+    """Everything ``main`` serves with, from its parsed arguments: the
+    config, the parameters (built under jit straight into the prefill
+    mesh's placement), the seeded prompt batch and ``generate``'s keyword
+    arguments: ``main`` is ``generate(cfg, params, prompts, **kwargs)``
+    plus reporting.
+    """
     cfg = resolve_config(args)
     if not cfg.supports_decode:
         raise SystemExit(f"{cfg.name} is encoder-only; no decode serving")
@@ -1639,8 +1677,10 @@ def main(argv=None) -> None:
         mesh = make_local_mesh(model_parallel=tp)
         rules = shd.PRESETS[args.preset]
 
-    key = jax.random.PRNGKey(0)
-    params = transformer.init_params(cfg, key)
+    params = transformer.init_params(
+        cfg, jax.random.PRNGKey(0),
+        shd.tree_shardings(transformer.abstract_params(cfg),
+                           transformer.param_axes(cfg), mesh, rules))
     rng = np.random.RandomState(0)
     prompts = rng.randint(0, cfg.vocab,
                           size=(args.batch, args.prompt_len)).astype(np.int32)
@@ -1653,19 +1693,29 @@ def main(argv=None) -> None:
     if args.priority_classes > 1:
         prios = (np.arange(args.batch)
                  % args.priority_classes).astype(np.int32)
+    kwargs = dict(max_new=args.max_new, temperature=args.temperature,
+                  prompt_lens=lens, mesh=mesh, rules=rules,
+                  act_transport=args.act_transport, decode_mesh=decode_mesh,
+                  decode_rules=decode_rules,
+                  cache_transfer=args.cache_transfer,
+                  kv_storage=args.kv_storage, stream=args.stream,
+                  slots=args.slots, workers=args.workers, evict=args.evict,
+                  paged=args.paged, page_size=args.page_size,
+                  pool_pages=args.pool_pages, horizon=args.horizon,
+                  priorities=prios, prefill_meshes=prefill_meshes)
+    return cfg, params, prompts, kwargs
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    enable_compile_cache(disaggregated=args.disagg)
+    cfg, params, prompts, kwargs = setup(args)
+    mesh, decode_mesh = kwargs["mesh"], kwargs["decode_mesh"]
+    lens = kwargs["prompt_lens"]
+    fan_in = args.workers > 1 or args.paged
 
     t0 = time.time()
-    out = generate(cfg, params, prompts, max_new=args.max_new,
-                   temperature=args.temperature, prompt_lens=lens,
-                   mesh=mesh, rules=rules, act_transport=args.act_transport,
-                   decode_mesh=decode_mesh, decode_rules=decode_rules,
-                   cache_transfer=args.cache_transfer,
-                   kv_storage=args.kv_storage,
-                   stream=args.stream, slots=args.slots,
-                   workers=args.workers, evict=args.evict,
-                   paged=args.paged, page_size=args.page_size,
-                   pool_pages=args.pool_pages, horizon=args.horizon,
-                   priorities=prios, prefill_meshes=prefill_meshes)
+    out = generate(cfg, params, prompts, **kwargs)
     dt = time.time() - t0
     n_tok = out.size
     mesh_desc = dict(zip(mesh.axis_names, mesh.devices.shape))

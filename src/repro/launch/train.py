@@ -24,6 +24,7 @@ from repro.core.orient import (ComputeCostTrait, FileCountReductionTrait,
                                FileEntropyTrait)
 from repro.data import DataPipeline, TokenShardWriter, merge_shards_fn
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.lst import Catalog, InMemoryStore
 from repro.lst.workload import SimClock
@@ -63,6 +64,7 @@ def build_autocomp(catalog, clock, target_bytes=1 << 22, top_k=4):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-lm-100m")
     ap.add_argument("--smoke", action="store_true",
@@ -88,8 +90,10 @@ def main() -> None:
     print(f"[data] shard files: {table.file_count()} "
           f"(plan {pipe.plan()[0].path.split('/')[-1]}...)")
 
-    key = jax.random.PRNGKey(0)
-    params = transformer.init_params(cfg, key)
+    params = transformer.init_params(
+        cfg, jax.random.PRNGKey(0),
+        shd.tree_shardings(transformer.abstract_params(cfg),
+                           transformer.param_axes(cfg), mesh))
     opt_state = opt_lib.init_state(
         params, error_feedback=args.grad_transport == "int8_ef")
     adamw = opt_lib.AdamWConfig(lr=1e-3, warmup_steps=10,

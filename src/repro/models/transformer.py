@@ -426,8 +426,13 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], mode: str,
 # public param API
 # ---------------------------------------------------------------------------
 
-def init_params(cfg: ModelConfig, key):
-    return tree_init(param_specs(cfg), key)
+def init_params(cfg: ModelConfig, key, shardings=None):
+    """Random parameters from ``key``. With ``shardings`` (one sharding
+    per leaf, e.g. ``dist.sharding.tree_shardings`` of the mesh that runs
+    them) the tree is built under jit straight into that placement, so no
+    device ever holds an unplaced full copy."""
+    placed = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(lambda k: tree_init(param_specs(cfg), k), **placed)(key)
 
 
 def abstract_params(cfg: ModelConfig):

@@ -130,7 +130,6 @@ def _data_parallel_step(grads_and_metrics, adamw, mesh, data_axis,
                         grad_transport, ef_block):
     """shard_map DDP wrapper: batch split over ``data_axis``, params/moments
     replicated, the gradient reduction explicit (and therefore measurable)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     w = mesh.shape[data_axis]
@@ -163,10 +162,10 @@ def _data_parallel_step(grads_and_metrics, adamw, mesh, data_axis,
         return spec
 
     ospec = opt_spec(grad_transport == "int8_ef")
-    return shard_map(device_step, mesh=mesh,
-                     in_specs=(P(), ospec, P(data_axis)),
-                     out_specs=(P(), ospec, P()),
-                     check_rep=False)
+    return jax.shard_map(device_step, mesh=mesh,
+                         in_specs=(P(), ospec, P(data_axis)),
+                         out_specs=(P(), ospec, P()),
+                         check_vma=False)
 
 
 def _check_act_transport(act_transport: Optional[str]) -> None:

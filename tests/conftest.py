@@ -6,9 +6,6 @@
    shim in ``_hypothesis_fallback.py``; and if even the shim cannot load,
    ``collect_ignore`` the hypothesis-based modules so collection never
    hard-errors on a missing optional dep (importorskip semantics).
-3. Patches ``jax.sharding.AbstractMesh`` to accept the newer
-   ``(axis_sizes, axis_names)`` signature on older jax (0.4.x takes a
-   ``((name, size), ...)`` tuple) so mesh-metadata tests run on either.
 """
 
 from __future__ import annotations
@@ -44,27 +41,3 @@ except ImportError:
         _install_hypothesis_fallback()
     except Exception:  # last resort: skip, never a collection error
         collect_ignore += _HYPOTHESIS_MODULES
-
-
-def _patch_abstract_mesh() -> None:
-    import jax.sharding as jsh
-
-    try:
-        jsh.AbstractMesh((1,), ("x",))
-        return                            # jax already takes (sizes, names)
-    except TypeError:
-        pass
-
-    _Orig = jsh.AbstractMesh
-
-    class AbstractMesh(_Orig):
-        def __init__(self, axis_sizes, axis_names=None, **kwargs):
-            if axis_names is not None:
-                super().__init__(tuple(zip(axis_names, axis_sizes)), **kwargs)
-            else:                         # old-style ((name, size), ...)
-                super().__init__(axis_sizes, **kwargs)
-
-    jsh.AbstractMesh = AbstractMesh
-
-
-_patch_abstract_mesh()

@@ -60,6 +60,39 @@ class TestRegistry:
         with pytest.raises(ValueError):
             api.register(bad)
 
+    @pytest.mark.parametrize("backend,interpret", [
+        ("tpu", False), ("cpu", True), ("gpu", None)])
+    def test_interpret_only_on_cpu(self, monkeypatch, backend, interpret):
+        """No hidden fallback: Mosaic kernels compile on TPU, interpret on
+        CPU, and refuse any other backend instead of interpreting."""
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if interpret is None:
+            with pytest.raises(RuntimeError, match="gpu"):
+                api.use_interpret()
+        else:
+            assert api.use_interpret() is interpret
+
+    def test_dispatch_records_point_and_source(self, tuned_dir):
+        """Every kernel dispatch records the point it ran and where the
+        point came from, so a run can show it never read a tuned cache."""
+        op = api.get_op("compact_pack")
+        args, kwargs = op.example(True)
+        api.call("compact_pack", *args, **kwargs)       # not recorded
+        with api.record_dispatches() as log:
+            api.call("compact_pack", *args, **kwargs)
+            api.call("compact_pack", *args, point={"block_chunks": 2},
+                     **kwargs)
+            tuned.store("compact_pack", op.shape_key(*args, **kwargs),
+                        {"block_chunks": 4}, objective_us=1.0,
+                        evaluations=1)
+            api.call("compact_pack", *args, **kwargs)
+            api.call("compact_pack", *args, use_ref=True, **kwargs)
+        got = [(d.op, d.point["block_chunks"], d.source) for d in log]
+        assert got == [("compact_pack", 1, "default"),
+                       ("compact_pack", 2, "explicit"),
+                       ("compact_pack", 4, "tuned")]
+
     def test_explicit_point_ignores_unknown_axes(self):
         op = api.get_op("rmsnorm")
         x = jnp.ones((64, 128), jnp.float32)
@@ -216,7 +249,43 @@ class TestTuneHarness:
 class TestFusedFilterPack:
     """The fused filter+pack kernel vs the filter-then-pack reference:
     bit-identical across plan shapes, keep fractions, and DMA
-    granularities (the whole point of exact_axes for compact_pack)."""
+    granularities (the whole point of exact_axes for compact_pack) — and
+    across the SMEM-bounded grid segments both compaction kernels run a
+    long plan in (``segment`` steps per ``pallas_call``; None = the
+    public path at the SMEM-derived segment length)."""
+
+    @staticmethod
+    def _check(src, cm, keep, segment):
+        from repro.kernels.compact_pack import compact_chunks
+        from repro.kernels.compact_pack.compact_pack import (
+            CHUNK_COLS, CHUNK_ROWS, CHUNK_TOKENS, compact_chunks_kernel,
+            compact_filter_kernel)
+        from repro.kernels.compact_pack.ops import plan_filter
+        from repro.kernels.compact_pack.ref import compact_chunks_ref
+        ref = np.asarray(compact_chunks(src, cm, use_ref=True,
+                                        keep_mask=keep))
+        if segment is None:
+            fused = np.asarray(compact_chunks(src, cm, keep_mask=keep))
+        else:
+            src3 = src.reshape(-1, CHUNK_ROWS, CHUNK_COLS)
+            sel, dest, done, out_idx, n_out = plan_filter(cm, keep)
+            fused = np.asarray(compact_filter_kernel(
+                src3, *map(jnp.asarray, (sel, dest, done, out_idx)), n_out,
+                interpret=True, segment=segment)).reshape(-1)
+            gather = compact_chunks_kernel(src3, jnp.asarray(cm),
+                                           interpret=True, segment=segment)
+            assert np.array_equal(np.asarray(gather), np.asarray(
+                compact_chunks_ref(src3, jnp.asarray(cm))))
+        assert np.array_equal(fused, ref)
+        assert fused.shape[0] == \
+            -(-int(keep.sum()) // CHUNK_ROWS) * CHUNK_TOKENS
+
+    @staticmethod
+    def _src(n_chunks, seed):
+        from repro.kernels.compact_pack.compact_pack import CHUNK_TOKENS
+        rng = np.random.RandomState(seed)
+        return jnp.asarray(rng.randint(0, 1 << 30, n_chunks * CHUNK_TOKENS,
+                                       np.int64).astype(np.int32))
 
     @pytest.mark.parametrize("counts,order", [
         ([4, 4, 4, 4], [3, 1, 2, 0]),
@@ -224,21 +293,32 @@ class TestFusedFilterPack:
         ([3, 1, 2], [2, 0, 1]),
     ])
     @pytest.mark.parametrize("frac", [0.0, 0.3, 1.0])
-    def test_fused_matches_reference(self, counts, order, frac):
-        from repro.kernels.compact_pack import (compact_chunks,
-                                                plan_compaction)
-        from repro.kernels.compact_pack.compact_pack import (CHUNK_ROWS,
-                                                             CHUNK_TOKENS)
-        n_src = sum(counts)
-        rng = np.random.RandomState(hash((tuple(counts), frac)) % (1 << 31))
-        src = jnp.asarray(rng.randint(0, 1 << 30,
-                                      n_src * CHUNK_TOKENS, np.int64)
-                          .astype(np.int32))
+    @pytest.mark.parametrize("segment", [None, 5])
+    def test_fused_matches_reference(self, counts, order, frac, segment):
+        from repro.kernels.compact_pack import plan_compaction
+        from repro.kernels.compact_pack.compact_pack import CHUNK_ROWS
+        seed = hash((tuple(counts), frac)) % (1 << 31)
+        src = self._src(sum(counts), seed)
         cm = plan_compaction(counts, fragment_order=order)
+        rng = np.random.RandomState(seed + 1)
         keep = rng.rand(len(cm) * CHUNK_ROWS) >= frac
-        fused = np.asarray(compact_chunks(src, cm, keep_mask=keep))
-        ref = np.asarray(compact_chunks(src, cm, use_ref=True,
-                                        keep_mask=keep))
-        assert np.array_equal(fused, ref)
-        assert fused.shape[0] == \
-            -(-int(keep.sum()) // CHUNK_ROWS) * CHUNK_TOKENS
+        self._check(src, cm, keep, segment)
+
+    @pytest.mark.parametrize("kept,segment", [
+        # 18 rows in 6 steps: the carry crosses both boundaries (1 and 2
+        # rows), and the final segment is the all-dropped flush step
+        # alone, emitting the 2-row carry
+        ([3, 3, 3, 3, 3, 3], 3),
+        # fully-dropped chunks never enter a segment; the kept chunks
+        # straddle the boundaries with a partial carry
+        ([3, 0, 0, 8, 5, 0, 0, 3], 2),
+        # chunk-aligned keeps: no carry and no flush step at any boundary
+        ([8, 8, 8], 1),
+    ])
+    def test_segment_edges(self, kept, segment):
+        from repro.kernels.compact_pack import plan_compaction
+        from repro.kernels.compact_pack.compact_pack import CHUNK_ROWS
+        cm = plan_compaction([len(kept)])
+        keep = (np.arange(CHUNK_ROWS)[None, :]
+                < np.asarray(kept)[:, None]).reshape(-1)
+        self._check(self._src(len(kept), len(kept)), cm, keep, segment)

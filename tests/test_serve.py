@@ -161,6 +161,78 @@ class TestServeArgs:
             and args.kv_storage == "f8"
         assert build_parser().parse_args([]).stream == "batch"
 
+    def test_setup_builds_params_in_their_mesh_placement(self):
+        """setup() initialises the parameters under jit straight into the
+        prefill mesh's shardings — the same values as an unplaced init,
+        and generate()'s own placement of them is then a no-op."""
+        from repro.dist import sharding as shd
+        from repro.launch.serve import build_parser, setup
+        args = build_parser().parse_args(["--arch", "paper-lm-100m"])
+        cfg, params, prompts, kw = setup(args)
+        want = shd.tree_shardings(transformer.abstract_params(cfg),
+                                  transformer.param_axes(cfg), kw["mesh"],
+                                  kw["rules"])
+        unplaced = transformer.init_params(cfg, jax.random.PRNGKey(0))
+        for p, s, e in zip(jax.tree.leaves(params), jax.tree.leaves(want),
+                           jax.tree.leaves(unplaced)):
+            assert p.sharding == s
+            np.testing.assert_array_equal(np.asarray(p), np.asarray(e))
+            assert jax.device_put(p, s) is p
+        assert prompts.shape == (args.batch, args.prompt_len)
+        out = generate(cfg, params, prompts, **{**kw, "max_new": 3})
+        assert out.shape == (args.batch, 3)
+        assert generate.last_stats["logits"] is None
+
+    @pytest.mark.parametrize("stream", ["batch", "slots"])
+    def test_keep_logits_rows_chose_the_tokens(self, stream):
+        """keep_logits leaves, per request and generated token, the logit
+        row it was chosen from: greedy tokens are their argmax, and the
+        slot stream keeps the same rows as the whole-batch path."""
+        from repro.launch.serve import _generate_slots, build_parser, setup
+        args = build_parser().parse_args(["--arch", "paper-lm-100m",
+                                          "--max-new", "4"])
+        cfg, params, prompts, kw = setup(args)
+        ref = generate(cfg, params, prompts, **kw, keep_logits=True)
+        rows_ref = generate.last_stats["logits"]
+        out = generate(cfg, params, prompts,
+                       **{**kw, "stream": stream}, keep_logits=True)
+        rows = (_generate_slots if stream == "slots"
+                else generate).last_stats["logits"]
+        assert rows.shape == (args.batch, 4, cfg.vocab)
+        assert rows.dtype == np.float32 and np.isfinite(rows).all()
+        np.testing.assert_array_equal(rows.argmax(-1), out)
+        np.testing.assert_array_equal(out, ref)
+        gap = np.abs(rows - rows_ref).max(-1) / rows_ref.std(-1)
+        assert gap.max() < 0.05, gap.max()
+
+    def test_compile_cache_dir(self, monkeypatch, tmp_path):
+        """The env var wins; unset, the cache is a fixed path in the
+        checkout, never a temp or per-run directory."""
+        import pathlib
+
+        from repro.launch import compile_cache
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.compile_cache_dir() == tmp_path
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert compile_cache.compile_cache_dir() == root / ".jax_cache"
+
+    def test_disaggregated_serving_runs_without_the_compile_cache(
+            self, monkeypatch, tmp_path):
+        """A disaggregated server turns the persistent cache off for its
+        process, env var or not; a colocated one keeps it."""
+        from repro.launch import compile_cache
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        was = jax.config.jax_enable_compilation_cache
+        try:
+            assert compile_cache.enable_compile_cache() == tmp_path
+            assert jax.config.jax_enable_compilation_cache
+            assert compile_cache.enable_compile_cache(
+                disaggregated=True) is None
+            assert not jax.config.jax_enable_compilation_cache
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+
 
 class TestKVStorageInt8:
     """int8-resident decode cache, single-device (the sharded/transfer

@@ -44,7 +44,8 @@ def cfg():
 @pytest.fixture(scope="module")
 def mesh():
     """The colocated (4, 2) mesh of the acceptance criteria."""
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 @pytest.fixture(scope="module")

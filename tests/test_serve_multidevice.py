@@ -33,7 +33,8 @@ BATCH, TOTAL = 8, 512        # decode horizon: cache gather dominates wire
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((DATA, MODEL), ("data", "model"))
+    return jax.make_mesh((DATA, MODEL), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 @pytest.fixture(scope="module")
