@@ -300,13 +300,14 @@ def check_kernel_hlo(gather, filt) -> None:
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-    hlo = ops._run.lower(sds(gather, CHUNK_ROWS, CHUNK_COLS), sds(gather),
-                         interpret=interpret).compile().as_text()
+    hlo = ops.compact_gather.lower(
+        sds(gather, CHUNK_ROWS, CHUNK_COLS), sds(gather),
+        interpret=interpret).compile().as_text()
     check("tpu_custom_call" in hlo,
           f"gather kernel HLO at {gather} chunks has tpu_custom_call "
           f"({hlo.count('tpu_custom_call')} segment calls)")
     n_src, n_steps, n_out = filt
-    hlo = ops._run_filter.lower(
+    hlo = ops.compact_filter.lower(
         sds(n_src, CHUNK_ROWS, CHUNK_COLS), sds(n_steps),
         sds(n_steps * CHUNK_ROWS), sds(n_steps), sds(n_steps),
         n_out=n_out, interpret=interpret).compile().as_text()

@@ -29,6 +29,8 @@ from repro.core.model import Candidate, Scope, generate_candidates
 from repro.core.observe import StatsCollector
 from repro.core.orient import TraitContext, compute_traits
 from repro.lst.catalog import Catalog
+from repro.spans import (AUTOCOMP_ACT, AUTOCOMP_CYCLE, AUTOCOMP_DECIDE,
+                         AUTOCOMP_PROPOSE, span)
 
 
 @dataclasses.dataclass
@@ -129,22 +131,24 @@ class AutoCompPipeline:
                   tables: Optional[Sequence] = None) -> CycleReport:
         t0 = time.perf_counter()
         rep = CycleReport()
+        with span(AUTOCOMP_CYCLE):
+            with span(AUTOCOMP_PROPOSE):
+                ranked = self.propose(catalog, tables=tables, report=rep)
 
-        ranked = self.propose(catalog, tables=tables, report=rep)
+            with span(AUTOCOMP_DECIDE):
+                selected = self.decide.select(ranked)
+                rep.n_selected = len(selected)
+                rep.n_unpriced = len(getattr(self.decide, "last_unpriced",
+                                             ()))
+                rep.selected_keys = [c.key for c in selected]
 
-        # decide
-        selected = self.decide.select(ranked)
-        rep.n_selected = len(selected)
-        rep.n_unpriced = len(getattr(self.decide, "last_unpriced", ()))
-        rep.selected_keys = [c.key for c in selected]
+            if self.act is not None:
+                with span(AUTOCOMP_ACT):
+                    rep.act = self.act.execute(selected)
+                    rep.deferred_keys = [c.key for c in rep.act.deferred]
 
-        # act
-        if self.act is not None:
-            rep.act = self.act.execute(selected)
-            rep.deferred_keys = [c.key for c in rep.act.deferred]
-
-        # feedback loop -> observe (updated file counts / layout changes)
-        if self.feedback_fn is not None and rep.act is not None:
-            self.feedback_fn(rep)
+            # feedback loop -> observe (updated file counts / layout changes)
+            if self.feedback_fn is not None and rep.act is not None:
+                self.feedback_fn(rep)
         rep.wall_s = time.perf_counter() - t0
         return rep

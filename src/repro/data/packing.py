@@ -27,6 +27,9 @@ from repro.kernels.compact_pack.compact_pack import (
 from repro.lst.compaction import CompactionTask
 from repro.lst.files import DataFile
 from repro.lst.table import LogStructuredTable
+from repro.spans import (MERGE_CONCAT, MERGE_DEVICE, MERGE_ENCODE,
+                         MERGE_FILTER, MERGE_READ, MERGE_RESLICE,
+                         MERGE_SHARDS, MERGE_STORE, span)
 
 
 def pack_tokens(stream: np.ndarray, batch: int, seq_len: int) -> np.ndarray:
@@ -67,42 +70,56 @@ def merge_shards_fn(table: LogStructuredTable, task: CompactionTask,
     included. Returns (DataFile, rows_dropped) — dropped counts only
     content rows the FILTER removed, not padding.
     """
-    payloads = []
-    lengths = []
-    for f in task.inputs:
-        raw = table.store.get(f.path)
-        payloads.append(sh.decode_shard_padded(raw))
-        lengths.append(len(sh.decode_shard(raw)))
-    flat = np.concatenate(payloads) if payloads else np.zeros(0, np.int32)
-    counts = [p.shape[0] // CHUNK_TOKENS for p in payloads]
-    chunk_map = plan_compaction(counts)
+    with span(MERGE_SHARDS, inputs=len(task.inputs),
+              input_bytes=int(task.input_bytes)):
+        with span(MERGE_READ):
+            payloads = []
+            lengths = []
+            for f in task.inputs:
+                raw = table.store.get(f.path)
+                payloads.append(sh.decode_shard_padded(raw))
+                lengths.append(len(sh.decode_shard(raw)))
+        with span(MERGE_CONCAT):
+            flat = np.concatenate(payloads) if payloads \
+                else np.zeros(0, np.int32)
+            counts = [p.shape[0] // CHUNK_TOKENS for p in payloads]
+            chunk_map = plan_compaction(counts)
 
-    if filter_fn is not None:
-        # merge_shards_fn plans fragments in input order, so the packed
-        # stream IS the concatenated stream and the row views coincide.
-        rows = flat.reshape(-1, CHUNK_COLS) if flat.size else \
-            np.zeros((0, CHUNK_COLS), np.int32)
-        valid = valid_row_mask(counts, lengths)
-        keep = np.asarray(filter_fn(rows, task), bool).reshape(-1) & valid
-        merged = np.asarray(compact_chunks(
-            jnp.asarray(flat), chunk_map, use_ref=not fused_filter,
-            keep_mask=keep))
-        tokens = merged[: int(keep.sum()) * CHUNK_COLS]
-        raw = sh.encode_shard(tokens)
-        table.store.put(out_path, raw)
-        out = DataFile(path=out_path, size_bytes=len(raw),
-                       num_rows=int(tokens.shape[0]), partition=task.scope,
-                       created_at=table.now_fn())
-        return out, int(valid.sum() - keep.sum())
+        if filter_fn is not None:
+            # merge_shards_fn plans fragments in input order, so the packed
+            # stream IS the concatenated stream and the row views coincide.
+            with span(MERGE_FILTER):
+                rows = flat.reshape(-1, CHUNK_COLS) if flat.size else \
+                    np.zeros((0, CHUNK_COLS), np.int32)
+                valid = valid_row_mask(counts, lengths)
+                keep = np.asarray(filter_fn(rows, task), bool).reshape(-1) \
+                    & valid
+            with span(MERGE_DEVICE):
+                merged = np.asarray(compact_chunks(
+                    jnp.asarray(flat), chunk_map, use_ref=not fused_filter,
+                    keep_mask=keep))
+            tokens = merged[: int(keep.sum()) * CHUNK_COLS]
+            with span(MERGE_ENCODE):
+                raw = sh.encode_shard(tokens)
+            with span(MERGE_STORE):
+                table.store.put(out_path, raw)
+            out = DataFile(path=out_path, size_bytes=len(raw),
+                           num_rows=int(tokens.shape[0]), partition=task.scope,
+                           created_at=table.now_fn())
+            return out, int(valid.sum() - keep.sum())
 
-    merged = np.asarray(compact_chunks(jnp.asarray(flat), chunk_map))
-    # re-encode with the true concatenated length (drop inter-shard padding
-    # bookkeeping: lengths are tracked per fragment)
-    tokens = np.concatenate([
-        merged[sum(c * CHUNK_TOKENS for c in counts[:i]):][:lengths[i]]
-        for i in range(len(counts))]) if counts else merged[:0]
-    raw = sh.encode_shard(tokens)
-    table.store.put(out_path, raw)
-    return DataFile(path=out_path, size_bytes=len(raw),
-                    num_rows=int(tokens.shape[0]), partition=task.scope,
-                    created_at=table.now_fn())
+        with span(MERGE_DEVICE):
+            merged = np.asarray(compact_chunks(jnp.asarray(flat), chunk_map))
+        # re-encode with the true concatenated length (drop inter-shard padding
+        # bookkeeping: lengths are tracked per fragment)
+        with span(MERGE_RESLICE):
+            tokens = np.concatenate([
+                merged[sum(c * CHUNK_TOKENS for c in counts[:i]):][:lengths[i]]
+                for i in range(len(counts))]) if counts else merged[:0]
+        with span(MERGE_ENCODE):
+            raw = sh.encode_shard(tokens)
+        with span(MERGE_STORE):
+            table.store.put(out_path, raw)
+        return DataFile(path=out_path, size_bytes=len(raw),
+                        num_rows=int(tokens.shape[0]), partition=task.scope,
+                        created_at=table.now_fn())

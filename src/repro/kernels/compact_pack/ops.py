@@ -123,12 +123,13 @@ def plan_filter(chunk_map: np.ndarray, keep_mask: np.ndarray
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def _run(src3, chunk_map, interpret):
+def compact_gather(src3, chunk_map, interpret):
     return compact_chunks_kernel(src3, chunk_map, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("n_out", "interpret"))
-def _run_filter(src3, chunk_sel, dest, completed, out_idx, n_out, interpret):
+def compact_filter(src3, chunk_sel, dest, completed, out_idx, n_out,
+                   interpret):
     return compact_filter_kernel(src3, chunk_sel, dest, completed, out_idx,
                                  n_out, interpret=interpret)
 
@@ -148,14 +149,16 @@ def _run_pack(point: Dict[str, int], src_tokens: jnp.ndarray,
             chunk_map, keep_mask)
         if n_out == 0:
             return jnp.zeros((0,), src_tokens.dtype)
-        out = _run_filter(src3, jnp.asarray(chunk_sel),
-                          jnp.asarray(dest), jnp.asarray(completed),
-                          jnp.asarray(out_idx), n_out, api.use_interpret())
+        out = compact_filter(src3, jnp.asarray(chunk_sel),
+                             jnp.asarray(dest), jnp.asarray(completed),
+                             jnp.asarray(out_idx), n_out,
+                             api.use_interpret())
         return out.reshape(-1)
     g, cm = coarsen_plan(chunk_map, src3.shape[0],
                          point.get("block_chunks", 1))
     srcg = src3.reshape(-1, g * CHUNK_ROWS, CHUNK_COLS) if g > 1 else src3
-    out = _run(srcg, jnp.asarray(cm, jnp.int32), api.use_interpret())
+    out = compact_gather(srcg, jnp.asarray(cm, jnp.int32),
+                         api.use_interpret())
     return out.reshape(-1)
 
 

@@ -82,6 +82,9 @@ from repro.dist import sharding as shd
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import registry, transformer
+from repro.spans import (SERVE_ADMIT, SERVE_DECODE, SERVE_EMIT, SERVE_GENERATE,
+                         SERVE_PREFILL, SERVE_SAMPLE, SERVE_SETUP,
+                         SERVE_TRANSFER_WAIT, span)
 from repro.train import step as step_lib
 
 
@@ -560,189 +563,208 @@ StateStore` row write), and the slot decodes from the request's own
     if n_slots < 1:
         raise ValueError(f"slot table needs at least one slot, got {slots}")
 
-    disagg = decode_mesh is not None
-    if disagg and mesh is None:
-        raise ValueError("disaggregated serving (decode_mesh=...) needs a "
-                         "prefill mesh too")
-    if mesh is not None and rules is None:
-        rules = shd.PRESETS["serve_sp"]
-    if disagg and decode_rules is None:
-        decode_rules = shd.PRESETS["serve_decode"]
-    dec_mesh = decode_mesh if disagg else mesh
-    dec_rules = decode_rules if disagg else rules
+    with span(SERVE_GENERATE, requests=b, slots=n_slots):
+        with span(SERVE_SETUP):
+            disagg = decode_mesh is not None
+            if disagg and mesh is None:
+                raise ValueError("disaggregated serving (decode_mesh=...) "
+                                 "needs a prefill mesh too")
+            if mesh is not None and rules is None:
+                rules = shd.PRESETS["serve_sp"]
+            if disagg and decode_rules is None:
+                decode_rules = shd.PRESETS["serve_decode"]
+            dec_mesh = decode_mesh if disagg else mesh
+            dec_rules = decode_rules if disagg else rules
 
-    prefill_fn = step_lib.make_prefill_step(cfg, act_transport)
-    dec_act = "bf16" if disagg and dec_rules is shd.PRESETS["serve_decode"] \
-        else act_transport
-    decode_fn = step_lib.make_decode_step(cfg, total, dec_act, kv_storage)
+            prefill_fn = step_lib.make_prefill_step(cfg, act_transport)
+            dec_act = "bf16" if disagg and \
+                dec_rules is shd.PRESETS["serve_decode"] else act_transport
+            decode_fn = step_lib.make_decode_step(cfg, total, dec_act,
+                                                  kv_storage)
 
-    pre_ctx = shd.axis_rules(mesh, rules) if mesh is not None \
-        else contextlib.nullcontext()
-    dec_ctx = shd.axis_rules(dec_mesh, dec_rules) if dec_mesh is not None \
-        else contextlib.nullcontext()
+            pre_ctx = shd.axis_rules(mesh, rules) if mesh is not None \
+                else contextlib.nullcontext()
+            dec_ctx = shd.axis_rules(dec_mesh, dec_rules) \
+                if dec_mesh is not None else contextlib.nullcontext()
 
-    slice_abs = transformer.abstract_cache(cfg, 1, total)
-    store_abs = transformer.abstract_cache(cfg, n_slots, total,
-                                           kv_storage=kv_storage)
+            slice_abs = transformer.abstract_cache(cfg, 1, total)
+            store_abs = transformer.abstract_cache(cfg, n_slots, total,
+                                                   kv_storage=kv_storage)
 
-    with pre_ctx:
-        params_pre = params
-        if mesh is not None:
-            p_shard = shd.tree_shardings(transformer.abstract_params(cfg),
-                                         transformer.param_axes(cfg),
-                                         mesh, rules)
-            params_pre = jax.device_put(params, p_shard)
-        prefill = jax.jit(prefill_fn)
-        grow = jax.jit(lambda c: grow_cache(c, slice_abs))
+            with pre_ctx:
+                params_pre = params
+                if mesh is not None:
+                    p_shard = shd.tree_shardings(
+                        transformer.abstract_params(cfg),
+                        transformer.param_axes(cfg), mesh, rules)
+                    params_pre = jax.device_put(params, p_shard)
+                prefill = jax.jit(prefill_fn)
 
-    with dec_ctx:
-        c_shard = mover = None
-        params_dec = params_pre
-        if dec_mesh is not None:
-            c_shard = shd.tree_shardings(
-                store_abs,
-                transformer.cache_axes(cfg, n_slots, total,
-                                       kv_storage=kv_storage),
-                dec_mesh, dec_rules)
-            if disagg:
-                p_shard_dec = shd.tree_shardings(
-                    transformer.abstract_params(cfg),
-                    transformer.param_axes(cfg), dec_mesh, dec_rules)
-                params_dec = jax.device_put(params, p_shard_dec)
-                slice_dst = shd.tree_shardings(
-                    slice_abs, transformer.cache_axes(cfg, 1, total),
-                    dec_mesh, dec_rules)
-                mover = make_cache_mover(cfg, 1, total, dec_mesh, dec_rules,
-                                         cache_transfer, slice_dst)
-        admit = jax.jit(make_slot_admit_step(
-            cfg, n_slots, total,
-            "bf16" if disagg else cache_transfer, kv_storage),
-            out_shardings=c_shard)
-        decode = jax.jit(decode_fn, out_shardings=(None, c_shard)) \
-            if c_shard is not None else jax.jit(decode_fn)
-        cache = jax.jit(lambda: jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), store_abs),
-            out_shardings=c_shard)()
+                def grow(c):
+                    return grow_cache(c, slice_abs)
+                # the program's name in a device trace: jit_grow_cache
+                grow.__name__ = "grow_cache"
+                grow = jax.jit(grow)
 
-    # ---- host-side slot table + double-buffered prefetch ----------------
-    key = jax.random.PRNGKey(seed)
-    out_tokens = [[] for _ in range(b)]
-    slot_req = [-1] * n_slots          # request id per slot, -1 = free
-    slot_tok = np.zeros((n_slots,), np.int32)
-    slot_pos = np.zeros((n_slots,), np.int32)
-    slot_keys: list = [None] * n_slots
-    next_req = 0
-    inflight: list = []                # at most one prefetched shipment
-    stats = {"admissions": 0, "transfer_wait_s": 0.0, "decode_steps": 0}
-    # per request, the (logits, row) each of its tokens was chosen from
-    kept = [[] for _ in range(b)] if keep_logits else None
+            with dec_ctx:
+                c_shard = mover = None
+                params_dec = params_pre
+                if dec_mesh is not None:
+                    c_shard = shd.tree_shardings(
+                        store_abs,
+                        transformer.cache_axes(cfg, n_slots, total,
+                                               kv_storage=kv_storage),
+                        dec_mesh, dec_rules)
+                    if disagg:
+                        p_shard_dec = shd.tree_shardings(
+                            transformer.abstract_params(cfg),
+                            transformer.param_axes(cfg), dec_mesh, dec_rules)
+                        params_dec = jax.device_put(params, p_shard_dec)
+                        slice_dst = shd.tree_shardings(
+                            slice_abs, transformer.cache_axes(cfg, 1, total),
+                            dec_mesh, dec_rules)
+                        mover = make_cache_mover(cfg, 1, total, dec_mesh,
+                                                 dec_rules, cache_transfer,
+                                                 slice_dst)
+                admit = jax.jit(make_slot_admit_step(
+                    cfg, n_slots, total,
+                    "bf16" if disagg else cache_transfer, kv_storage),
+                    out_shardings=c_shard)
+                decode = jax.jit(decode_fn, out_shardings=(None, c_shard)) \
+                    if c_shard is not None else jax.jit(decode_fn)
 
-    def start_prefetch():
-        """Prefill + ship the next pending request (async dispatch): the
-        wire transfer overlaps whatever decode steps run before the next
-        admission — the double buffer."""
-        nonlocal next_req
-        if next_req >= b or inflight:
-            return
-        i = next_req
-        next_req += 1
-        with pre_ctx:
-            if caps.row_state:
-                # ring-buffer / recurrent state: pad tokens must never
-                # enter the per-row state, so prefill the request at its
-                # exact length (one compile per distinct length) instead
-                # of masking a padded batch
-                logits, c = prefill(params_pre, {
-                    "tokens": jnp.asarray(prompts[i:i + 1, :lens[i]])})
-            else:
-                logits, c = prefill(params_pre, {
-                    "tokens": jnp.asarray(prompts[i:i + 1]),
-                    "last_pos": jnp.asarray(lens[i:i + 1] - 1)})
-            slc = grow(c)
-            tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
-        if mover is not None:
-            slc = mover(slc)
-        inflight.append((i, slc, tok0, logits))
+                def init_cache():
+                    return jax.tree.map(
+                        lambda s: jnp.zeros(s.shape, s.dtype), store_abs)
+                cache = jax.jit(init_cache, out_shardings=c_shard)()
 
-    def emit(i, t, slot):
-        out_tokens[i].append(int(t))
-        if len(out_tokens[i]) >= max_new:
-            slot_req[slot] = -1        # free the slot for reuse
+        # ---- host-side slot table + double-buffered prefetch ----------------
+        key = jax.random.PRNGKey(seed)
+        out_tokens = [[] for _ in range(b)]
+        slot_req = [-1] * n_slots          # request id per slot, -1 = free
+        slot_tok = np.zeros((n_slots,), np.int32)
+        slot_pos = np.zeros((n_slots,), np.int32)
+        slot_keys: list = [None] * n_slots
+        next_req = 0
+        inflight: list = []                # at most one prefetched shipment
+        stats = {"admissions": 0, "transfer_wait_s": 0.0, "decode_steps": 0}
+        # per request, the (logits, row) each of its tokens was chosen from
+        kept = [[] for _ in range(b)] if keep_logits else None
 
-    def admit_next(slot):
-        nonlocal cache
-        if not inflight:
-            start_prefetch()
-        i, slc, tok0, logits0 = inflight.pop(0)
-        t0 = time.time()
-        jax.block_until_ready(slc)     # what the overlap failed to hide
-        stats["transfer_wait_s"] += time.time() - t0
-        with dec_ctx:
-            cache = admit(cache, slc, jnp.asarray(slot, jnp.int32))
-        stats["admissions"] += 1
-        slot_req[slot] = i
-        slot_pos[slot] = lens[i]
-        slot_tok[slot] = int(np.asarray(tok0)[0])
-        slot_keys[slot] = jax.random.fold_in(key, i)
+        def start_prefetch():
+            """Prefill + ship the next pending request (async dispatch): the
+            wire transfer overlaps whatever decode steps run before the next
+            admission — the double buffer."""
+            nonlocal next_req
+            if next_req >= b or inflight:
+                return
+            i = next_req
+            next_req += 1
+            with span(SERVE_PREFILL, request=i):
+                with pre_ctx:
+                    if caps.row_state:
+                        # ring-buffer / recurrent state: pad tokens must
+                        # never enter the per-row state, so prefill the
+                        # request at its exact length (one compile per
+                        # distinct length) instead of masking a padded batch
+                        logits, c = prefill(params_pre, {
+                            "tokens": jnp.asarray(prompts[i:i + 1,
+                                                          :lens[i]])})
+                    else:
+                        logits, c = prefill(params_pre, {
+                            "tokens": jnp.asarray(prompts[i:i + 1]),
+                            "last_pos": jnp.asarray(lens[i:i + 1] - 1)})
+                    slc = grow(c)
+                    tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
+                if mover is not None:
+                    slc = mover(slc)
+                inflight.append((i, slc, tok0, logits))
+
+        def emit(i, t, slot):
+            out_tokens[i].append(int(t))
+            if len(out_tokens[i]) >= max_new:
+                slot_req[slot] = -1        # free the slot for reuse
+
+        def admit_next(slot):
+            nonlocal cache
+            if not inflight:
+                start_prefetch()
+            i, slc, tok0, logits0 = inflight.pop(0)
+            with span(SERVE_ADMIT, request=i):
+                with span(SERVE_TRANSFER_WAIT):
+                    t0 = time.time()
+                    # what the overlap failed to hide
+                    jax.block_until_ready(slc)
+                    stats["transfer_wait_s"] += time.time() - t0
+                with dec_ctx:
+                    cache = admit(cache, slc, jnp.asarray(slot, jnp.int32))
+                stats["admissions"] += 1
+                slot_req[slot] = i
+                slot_pos[slot] = lens[i]
+                slot_tok[slot] = int(np.asarray(tok0)[0])
+                slot_keys[slot] = jax.random.fold_in(key, i)
+                if kept is not None:
+                    kept[i].append((logits0, 0))
+                emit(i, slot_tok[slot], slot)  # the prefill token
+                start_prefetch()           # double buffer the next shipment
+
+        start_prefetch()
+        while True:
+            # keep admitting until the table is full or the queue drains — a
+            # slot freed AT admission (max_new == 1: the prefill token is the
+            # whole request) must be refilled in the same pass, or pending
+            # requests would be dropped when every slot reads free below
+            admitted = True
+            while admitted:
+                admitted = False
+                for s_ in range(n_slots):
+                    if slot_req[s_] < 0 and (inflight or next_req < b):
+                        admit_next(s_)
+                        admitted = True
+            if all(r < 0 for r in slot_req):
+                break                      # nothing active, nothing pending
+            with span(SERVE_DECODE, step=stats["decode_steps"]):
+                tok = jnp.asarray(slot_tok[:, None])
+                pos = jnp.asarray(slot_pos)
+                with dec_ctx:
+                    logits, cache = decode(params_dec, cache,
+                                           {"tokens": tok, "pos": pos})
+            stats["decode_steps"] += 1
+            with span(SERVE_SAMPLE):
+                if temperature > 0:
+                    logits_np = np.asarray(logits, np.float32)
+                    nxt = np.zeros((n_slots,), np.int32)
+                    for s_ in range(n_slots):
+                        if slot_req[s_] < 0:
+                            continue
+                        slot_keys[s_], sub = jax.random.split(slot_keys[s_])
+                        nxt[s_] = int(jax.random.categorical(
+                            sub, jnp.asarray(logits_np[s_]) / temperature))
+                else:
+                    nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
+            with span(SERVE_EMIT):
+                for s_ in range(n_slots):
+                    i = slot_req[s_]
+                    if i < 0:
+                        continue
+                    slot_tok[s_] = nxt[s_]
+                    slot_pos[s_] += 1
+                    if kept is not None:
+                        kept[i].append((logits, s_))
+                    emit(i, nxt[s_], s_)
+
+        assert all(len(ts) == max_new for ts in out_tokens)
         if kept is not None:
-            kept[i].append((logits0, 0))
-        emit(i, slot_tok[slot], slot)  # the prefill token
-        start_prefetch()               # double buffer the next shipment
+            host = {}                      # one device-to-host copy per array
 
-    start_prefetch()
-    while True:
-        # keep admitting until the table is full or the queue drains — a
-        # slot freed AT admission (max_new == 1: the prefill token is the
-        # whole request) must be refilled in the same pass, or pending
-        # requests would be dropped when every slot reads free below
-        admitted = True
-        while admitted:
-            admitted = False
-            for s_ in range(n_slots):
-                if slot_req[s_] < 0 and (inflight or next_req < b):
-                    admit_next(s_)
-                    admitted = True
-        if all(r < 0 for r in slot_req):
-            break                      # nothing active, nothing pending
-        tok = jnp.asarray(slot_tok[:, None])
-        pos = jnp.asarray(slot_pos)
-        with dec_ctx:
-            logits, cache = decode(params_dec, cache,
-                                   {"tokens": tok, "pos": pos})
-        stats["decode_steps"] += 1
-        if temperature > 0:
-            logits_np = np.asarray(logits, np.float32)
-            nxt = np.zeros((n_slots,), np.int32)
-            for s_ in range(n_slots):
-                if slot_req[s_] < 0:
-                    continue
-                slot_keys[s_], sub = jax.random.split(slot_keys[s_])
-                nxt[s_] = int(jax.random.categorical(
-                    sub, jnp.asarray(logits_np[s_]) / temperature))
-        else:
-            nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-        for s_ in range(n_slots):
-            i = slot_req[s_]
-            if i < 0:
-                continue
-            slot_tok[s_] = nxt[s_]
-            slot_pos[s_] += 1
-            if kept is not None:
-                kept[i].append((logits, s_))
-            emit(i, nxt[s_], s_)
-
-    assert all(len(ts) == max_new for ts in out_tokens)
-    if kept is not None:
-        host = {}                      # one device-to-host copy per array
-
-        def row(x, r):
-            if id(x) not in host:
-                host[id(x)] = np.asarray(x, np.float32)
-            return host[id(x)][r]
-        kept = np.stack([[row(x, r) for x, r in rows] for rows in kept])
-    stats["logits"] = kept
-    _generate_slots.last_stats = stats     # launcher reporting hook
-    return np.asarray(out_tokens, np.int32)
+            def row(x, r):
+                if id(x) not in host:
+                    host[id(x)] = np.asarray(x, np.float32)
+                return host[id(x)][r]
+            kept = np.stack([[row(x, r) for x, r in rows] for rows in kept])
+        stats["logits"] = kept
+        _generate_slots.last_stats = stats     # launcher reporting hook
+        return np.asarray(out_tokens, np.int32)
 
 
 def _generate_fanin(cfg, params, prompts: np.ndarray, max_new: int,

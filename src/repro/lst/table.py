@@ -26,6 +26,8 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro.lst.files import DataFile, ManifestFile, Snapshot, TableMetadata
 from repro.lst.storage import ObjectStore
+from repro.spans import (TABLE_COMMIT, TABLE_MANIFEST, TABLE_METADATA,
+                         TABLE_REBASE, span)
 
 
 class CommitConflict(Exception):
@@ -164,35 +166,40 @@ class LogStructuredTable:
         return 1
 
     def _persist_metadata(self) -> None:
-        path = f"{self.meta.table_id}/metadata/v{self.meta.version}.json"
-        self.store.put(path, self.meta.serialize())
+        with span(TABLE_METADATA):
+            path = f"{self.meta.table_id}/metadata/v{self.meta.version}.json"
+            self.store.put(path, self.meta.serialize())
 
     def _try_commit(self, txn: "Transaction") -> Snapshot:
-        with self._lock:
-            if self.meta.version != txn.base_version:
-                self.cas_retries += 1       # stale base: CAS retry happened
-                self._validate(txn)
-            # rebase onto current state
-            base = self.current_files()
-            removed_paths = {f.path for f in txn.removed}
-            if txn.operation in ("replace", "delete"):
-                missing = removed_paths - {f.path for f in base}
-                if missing:
-                    raise CommitConflict(
-                        f"files vanished under rewrite: {sorted(missing)[:3]}",
-                        kind="stale_files")
-            new_files = tuple(f for f in base if f.path not in removed_paths
-                              ) + tuple(txn.added)
+        with self._lock, span(TABLE_COMMIT, added=len(txn.added),
+                              removed=len(txn.removed)):
+            with span(TABLE_REBASE):
+                if self.meta.version != txn.base_version:
+                    self.cas_retries += 1   # stale base: CAS retry happened
+                    self._validate(txn)
+                # rebase onto current state
+                base = self.current_files()
+                removed_paths = {f.path for f in txn.removed}
+                if txn.operation in ("replace", "delete"):
+                    missing = removed_paths - {f.path for f in base}
+                    if missing:
+                        raise CommitConflict(
+                            f"files vanished under rewrite: "
+                            f"{sorted(missing)[:3]}", kind="stale_files")
+                new_files = tuple(f for f in base
+                                  if f.path not in removed_paths
+                                  ) + tuple(txn.added)
             sid = self._next_snapshot_id()
             seq = (self.meta.snapshots[-1].sequence_number + 1
                    if self.meta.snapshots else 1)
-            manifest = ManifestFile(
-                f"{self.table_id}/metadata/manifest-{sid}.json",
-                tuple(txn.added), tuple(sorted(removed_paths)))
-            self.store.put(manifest.path, manifest.serialize())
-            mlist_path = f"{self.table_id}/metadata/snap-{sid}.json"
-            self.store.put(mlist_path, json.dumps(
-                {"manifests": [manifest.path]}).encode())
+            with span(TABLE_MANIFEST):
+                manifest = ManifestFile(
+                    f"{self.table_id}/metadata/manifest-{sid}.json",
+                    tuple(txn.added), tuple(sorted(removed_paths)))
+                self.store.put(manifest.path, manifest.serialize())
+                mlist_path = f"{self.table_id}/metadata/snap-{sid}.json"
+                self.store.put(mlist_path, json.dumps(
+                    {"manifests": [manifest.path]}).encode())
             snap = Snapshot(
                 snapshot_id=sid, parent_id=self.meta.current_snapshot_id,
                 sequence_number=seq, timestamp=self.now_fn(),
