@@ -8,7 +8,9 @@ Cache layouts (per layer, no leading layers axis here):
   GQA : {"k": (B, S_c, Hkv, D), "v": (B, S_c, Hkv, D)}   S_c = window or seq
   MLA : {"latent": (B, S_c, kv_lora), "k_rope": (B, S_c, rope_dim)}
 Cached K is stored *post-RoPE* (standard for ring buffers: relative property
-is preserved because Q is rotated at query position).
+is preserved because Q is rotated at query position). MLA decode attends
+over the latent cache in absorbed form; training and prefill expand the
+latent into per-head K/V.
 """
 
 from __future__ import annotations
@@ -257,7 +259,47 @@ def _mla_expand(cfg, p, latent, k_rope):
     return jnp.concatenate([k_nope, kr], -1), v
 
 
+def _mla_decode_attention(cfg, p, q, latent, k_rope, k_positions, pos):
+    """Single-token MLA attention in absorbed (latent) form.
+
+    The same attention as expanding the cache with :func:`_mla_expand`
+    and calling ``decode_attention``, in another order (DeepSeek-V2's
+    inference form): ``wk_b`` folds into the query, scores are taken
+    against the latent and the shared rope key, and ``wv_b`` applies
+    after the weighted sum of latents. The cache is read as it is stored,
+    and no per-head K or V exists. Operands keep their dtype, products
+    accumulate in float32, and the absorbed query ``q_lat`` and output
+    ``o_lat`` stay float32. The scale is the per-head query width's.
+
+    q:(B,1,H,dn+dr), latent:(B,S,rkv), k_rope:(B,S,dr) -> (B,1,H,dv).
+    """
+    b, _, _, d = q.shape
+    f32 = jnp.float32
+    q_nope = q[:, 0, :, :cfg.nope_head_dim]                     # (B,H,dn)
+    q_rope = q[:, 0, :, cfg.nope_head_dim:]                     # (B,H,dr)
+    q_lat = constrain(jnp.einsum("bhk,rhk->bhr", q_nope, p["wk_b"],
+                                 preferred_element_type=f32),
+                      "batch", "heads", None)                   # (B,H,rkv)
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, latent,
+                    preferred_element_type=f32)
+         + jnp.einsum("bhk,bsk->bhs", q_rope, k_rope,
+                      preferred_element_type=f32)) * (1.0 / np.sqrt(d))
+    valid = common.decode_mask(k_positions, pos, b, latent.shape[1])
+    s = jnp.where(valid[:, None, :], s, common.NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    o_lat = constrain(jnp.einsum("bhs,bsr->bhr", w, latent,
+                                 preferred_element_type=f32),
+                      "batch", "heads", None)                   # (B,H,rkv)
+    out = jnp.einsum("bhr,rhv->bhv", o_lat, p["wv_b"],
+                     preferred_element_type=f32)
+    return out[:, None].astype(q.dtype)
+
+
 def mla_apply(cfg: ModelConfig, p, x, mode, cache, pos, cache_len_total):
+    """MLA block. Decode attends over the latent cache in absorbed form
+    (:func:`_mla_decode_attention`); training and prefill expand the latent
+    into per-head K/V (:func:`_mla_expand`), the cheaper form over a whole
+    sequence."""
     b, s, _ = x.shape
     if mode == "decode":
         positions = jnp.broadcast_to(jnp.asarray(pos, jnp.int32)[..., None],
@@ -267,13 +309,13 @@ def mla_apply(cfg: ModelConfig, p, x, mode, cache, pos, cache_len_total):
         kr_new = k_rope[:, :, None, :]
         if storage == "f8":
             # f8-resident latent cache: scale-free e4m3, upcast at the
-            # same read-time boundary as int8 (the latent expansion)
+            # same read-time boundary as int8 (the latent attention)
             latent = collectives.cast_f8(latent)
             kr_new = collectives.cast_f8(kr_new)
         if storage == "int8":
             # int8-resident latent cache (MLA's read-time boundary is the
-            # per-head expansion, so dequantization happens just before
-            # _mla_expand instead of inside decode_attention)
+            # latent attention, so dequantization happens just before
+            # _mla_decode_attention instead of inside decode_attention)
             latent, lat_sc = collectives.quantize_int8_lastdim(latent)
             kr_new, kr_sc = collectives.quantize_int8_lastdim(kr_new)
             lat_scale = constrain(ring_update(cache["latent_scale"], lat_sc,
@@ -287,7 +329,7 @@ def mla_apply(cfg: ModelConfig, p, x, mode, cache, pos, cache_len_total):
                              "batch", "kv_seq", None, None)
         # decode's activation all-gather (MLA form): the latent cache is
         # the compressed KV state — gather it (s8 under int8 transport, or
-        # natively s8 when int8-resident) before the per-head expansion.
+        # natively s8 when int8-resident) before the latent attention.
         lat_att = collectives.act_gather(lat_cache, "batch", None, None)
         kr_att = collectives.act_gather(kr_cache, "batch", None, None, None)
         if storage == "int8":
@@ -300,9 +342,9 @@ def mla_apply(cfg: ModelConfig, p, x, mode, cache, pos, cache_len_total):
         elif storage == "f8":
             lat_att = collectives.uncast_f8(lat_att, x.dtype)
             kr_att = collectives.uncast_f8(kr_att, x.dtype)
-        k, v = _mla_expand(cfg, p, lat_att, kr_att[..., 0, :])
         kpos = cache_slot_positions(cache_len_total + 1, lat_cache.shape[1], pos)
-        out = decode_attention(q, k, v, kpos, pos)
+        out = _mla_decode_attention(cfg, p, q, lat_att, kr_att[..., 0, :],
+                                    kpos, pos)
         new_cache = {"latent": lat_cache, "k_rope": kr_cache}
         if storage == "int8":
             new_cache["latent_scale"] = lat_scale

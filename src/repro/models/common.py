@@ -203,6 +203,21 @@ def blockwise_attention(q, k, v, *, causal=True, window=0,
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dv)
 
 
+def decode_mask(k_positions, pos, b: int, s: int) -> jnp.ndarray:
+    """(B, S) mask of the cache slots a single decode token attends to.
+
+    ``k_positions``: (S,) or per-row (B,S) absolute slot positions (-1
+    invalid); ``pos``: scalar or per-row (B,) current position. A slot is
+    valid when it holds a position and that position is not past the
+    row's own."""
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    kp = jnp.asarray(k_positions, jnp.int32)
+    if kp.ndim == 1:
+        kp = kp[None, :]
+    valid = (kp >= 0) & (kp <= pos_b[:, None])          # (B or 1, S) -> (B,S)
+    return jnp.broadcast_to(valid, (b, s))
+
+
 def decode_attention(q, k_cache, v_cache, k_positions, pos,
                      k_scale=None, v_scale=None):
     """Single-token attention against a cache. q:(B,1,H,D), caches (B,S,Hkv,D).
@@ -233,12 +248,7 @@ def decode_attention(q, k_cache, v_cache, k_positions, pos,
     scale = 1.0 / np.sqrt(d)
     qg = q.reshape(b, hkv, group, d).astype(jnp.float32)
     s = jnp.einsum("bhgd,bshd->bhgs", qg, k_cache.astype(jnp.float32)) * scale
-    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    kp = jnp.asarray(k_positions, jnp.int32)
-    if kp.ndim == 1:
-        kp = kp[None, :]
-    valid = (kp >= 0) & (kp <= pos_b[:, None])          # (B or 1, S) -> (B,S)
-    valid = jnp.broadcast_to(valid, (b, k_cache.shape[1]))
+    valid = decode_mask(k_positions, pos, b, k_cache.shape[1])
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     s = constrain(s, "batch", "kv_heads", None, "kv_seq")
     p = jax.nn.softmax(s, axis=-1)
