@@ -91,7 +91,7 @@ METRICS["fleet_retention_bytes_rewritten"] = "lower"
 # deterministic — a plan change that re-reads dropped rows fails even if
 # the stopwatch is noisy).
 for _op in ("compact_pack", "flash_attn", "decode_attn", "paged_attn",
-            "rmsnorm", "expert_a2a"):
+            "rmsnorm", "expert_a2a", "expert_gmm"):
     METRICS[f"kernel_{_op}_tuned_s"] = "lower"
 METRICS["kernel_compact_filter_s"] = "lower"
 METRICS["kernel_compact_filter_hbm_bytes"] = "lower"

@@ -12,7 +12,8 @@ import dataclasses
 import importlib
 from typing import Optional, Tuple
 
-FAMILIES = ("dense", "moe", "mla", "hybrid", "ssm_xlstm", "encoder_audio", "vlm")
+FAMILIES = ("dense", "moe", "mla", "mla_moe", "hybrid", "ssm_xlstm",
+            "encoder_audio", "vlm")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -38,8 +39,16 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
 
-    # --- MLA (family == "mla") ---
-    q_lora_rank: int = 0
+    # --- held-share dropless experts (family == "mla_moe": DeepSeek-V3
+    # routing, sigmoid scores + a selection bias, top-k weights normalised)
+    n_shared_experts: int = 0        # shared SwiGLU of width n_shared * d_ff_expert
+    first_k_dense: int = 0           # leading layers with a dense MLP of width d_ff
+    routed_scaling: float = 1.0      # routed output times this
+    experts_held: int = 0            # routed experts this chip holds (0 => all)
+    expert_offset: int = 0           # the first of them
+
+    # --- MLA (families "mla", "mla_moe") ---
+    q_lora_rank: int = 0             # 0 => direct query projection, no q_norm
     kv_lora_rank: int = 0
     rope_head_dim: int = 0
     nope_head_dim: int = 0
@@ -68,8 +77,20 @@ class ModelConfig:
             raise ValueError(f"unknown family {self.family!r}")
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.expert_offset + self.n_held_experts > self.n_experts:
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + self.n_held_experts - 1} "
+                f"held, but the model has {self.n_experts}")
 
     # ---- derived quantities -------------------------------------------------
+    @property
+    def mla(self) -> bool:
+        return self.family in ("mla", "mla_moe")
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
     @property
     def q_dim(self) -> int:
         return self.n_heads * self.head_dim
@@ -100,9 +121,12 @@ def _param_count(c: ModelConfig, active_only: bool) -> int:
     d = c.d_model
     emb = c.vocab * d * (1 if c.tie_embeddings else 2)
     per_layer = 0
-    if c.family == "mla":
+    if c.mla:
         qk_head = c.nope_head_dim + c.rope_head_dim
-        per_layer += d * c.q_lora_rank + c.q_lora_rank * c.n_heads * qk_head
+        if c.q_lora_rank:
+            per_layer += d * c.q_lora_rank + c.q_lora_rank * c.n_heads * qk_head
+        else:
+            per_layer += d * c.n_heads * qk_head
         per_layer += d * (c.kv_lora_rank + c.rope_head_dim)
         per_layer += c.kv_lora_rank * c.n_heads * (c.nope_head_dim + c.v_head_dim)
         per_layer += c.n_heads * c.v_head_dim * d
@@ -127,6 +151,15 @@ def _param_count(c: ModelConfig, active_only: bool) -> int:
         per_layer += d_inner * (c.ssm_state * 2 + 1)  # B, C, dt projections (fused, low rank)
         per_layer += d_inner * c.ssm_conv + d_inner   # conv + A/D
         per_layer += d_inner * d              # out proj (shared with attn out add)
+    if c.family == "mla_moe":
+        # the whole published model (every routed expert) or, active, the
+        # top_k routed experts a token goes through; shared experts always
+        e = c.top_k if active_only else c.n_experts
+        moe_layer = (d * c.n_experts + c.n_experts          # router, bias
+                     + (e + c.n_shared_experts) * 3 * d * c.d_ff_expert)
+        n_moe = c.n_layers - c.first_k_dense
+        return (emb + c.n_layers * (per_layer + 2 * d)
+                + c.first_k_dense * 3 * d * c.d_ff + n_moe * moe_layer)
     if c.family == "moe":
         e = c.n_experts if not active_only else c.top_k
         per_layer += d * c.n_experts          # router
@@ -148,6 +181,7 @@ _REGISTRY = {
     "hymba-1.5b": "repro.configs.hymba_1_5b",
     "internvl2-2b": "repro.configs.internvl2_2b",
     "xlstm-125m": "repro.configs.xlstm_125m",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "paper-lm-100m": "repro.configs.paper_lm_100m",
 }
 
@@ -172,9 +206,19 @@ def smoke_config(arch_id: str, *, n_layers: int = 2, vocab: int = 256) -> ModelC
     )
     if c.family == "moe":
         kw.update(n_experts=4, top_k=2, d_ff_expert=32, d_ff=0)
-    if c.family == "mla":
-        kw.update(q_lora_rank=32, kv_lora_rank=16, rope_head_dim=8,
-                  nope_head_dim=8, v_head_dim=16, head_dim=16)
+    if c.mla:
+        kw.update(q_lora_rank=32 if c.q_lora_rank else 0, kv_lora_rank=16,
+                  rope_head_dim=8, nope_head_dim=8, v_head_dim=16,
+                  head_dim=16)
+    if c.family == "mla_moe":
+        # 8 experts, this chip holding the second half: absent experts,
+        # the shared experts and the leading dense layer all stay in play
+        kw.update(n_experts=8, top_k=2, d_ff_expert=32,
+                  n_shared_experts=c.n_shared_experts,
+                  first_k_dense=min(c.first_k_dense, n_layers - 1),
+                  routed_scaling=c.routed_scaling,
+                  experts_held=4 if c.experts_held else 0,
+                  expert_offset=4 if c.experts_held else 0)
     if c.family == "hybrid":
         kw.update(ssm_state=8, ssm_expand=2, ssm_conv=4, attn_window=32)
     if c.family == "ssm_xlstm":

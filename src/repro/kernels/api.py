@@ -99,6 +99,7 @@ _BUILTIN_OPS = (
     "repro.kernels.paged_attn.ops",
     "repro.kernels.rmsnorm.ops",
     "repro.kernels.expert_a2a.ops",
+    "repro.kernels.expert_gmm.ops",
 )
 
 

@@ -540,7 +540,11 @@ StateStore` row write), and the slot decodes from the request's own
     steps that run before the next slot frees; the wall-clock wait the
     overlap failed to hide is recorded in ``_generate_slots.last_stats``
     (the launcher prints it), with the kept logit rows under
-    ``keep_logits`` (see :func:`generate`).
+    ``keep_logits`` (see :func:`generate`). A configuration whose decode
+    step counts (``step_lib.decode_counters``: the held-share expert
+    layer's ``moe_held_pairs`` and ``moe_max_expert_tokens``, over every
+    row the steps compute) accumulates them on the device through the
+    steps and reads them back once, into ``last_stats``.
 
     Returns tokens ``(B, max_new)``; greedy tokens are token-for-token
     identical to the whole-batch path (per-row attention independence —
@@ -630,7 +634,12 @@ StateStore` row write), and the slot decodes from the request's own
                     cfg, n_slots, total,
                     "bf16" if disagg else cache_transfer, kv_storage),
                     out_shardings=c_shard)
-                decode = jax.jit(decode_fn, out_shardings=(None, c_shard)) \
+                # device-side counters the decode steps accumulate (the
+                # held-share expert layer's), read back once at the end
+                counters = step_lib.decode_counters(cfg)
+                dec_out = (None, c_shard) if counters is None \
+                    else (None, c_shard, None)
+                decode = jax.jit(decode_fn, out_shardings=dec_out) \
                     if c_shard is not None else jax.jit(decode_fn)
 
                 def init_cache():
@@ -727,8 +736,13 @@ StateStore` row write), and the slot decodes from the request's own
                 tok = jnp.asarray(slot_tok[:, None])
                 pos = jnp.asarray(slot_pos)
                 with dec_ctx:
-                    logits, cache = decode(params_dec, cache,
-                                           {"tokens": tok, "pos": pos})
+                    if counters is None:
+                        logits, cache = decode(params_dec, cache,
+                                               {"tokens": tok, "pos": pos})
+                    else:
+                        logits, cache, counters = decode(
+                            params_dec, cache, {"tokens": tok, "pos": pos},
+                            counters)
             stats["decode_steps"] += 1
             with span(SERVE_SAMPLE):
                 if temperature > 0:
@@ -754,6 +768,9 @@ StateStore` row write), and the slot decodes from the request's own
                     emit(i, nxt[s_], s_)
 
         assert all(len(ts) == max_new for ts in out_tokens)
+        if counters is not None:
+            stats.update({k: int(v) for k, v in
+                          jax.device_get(counters).items()})
         if kept is not None:
             host = {}                      # one device-to-host copy per array
 

@@ -1,5 +1,6 @@
 """Attention layers: GQA (optional QKV bias, optional sliding window) and
-MLA (Multi-head Latent Attention, MiniCPM3/DeepSeek-style).
+MLA (Multi-head Latent Attention, MiniCPM3/DeepSeek-style; with a
+compressed query, or a direct query projection when ``q_lora_rank`` is 0).
 
 Each layer exposes ``specs(cfg)`` (parameter declarations) and
 ``apply(cfg, p, x, mode, cache, pos)`` -> (out, new_cache).
@@ -222,24 +223,31 @@ def mla_specs(cfg: ModelConfig) -> Dict[str, Spec]:
     d, h = cfg.d_model, cfg.n_heads
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.nope_head_dim, cfg.rope_head_dim, cfg.v_head_dim
-    return {
-        "wq_a": Spec((d, rq), ("embed", "q_lora")),
-        "wq_b": Spec((rq, h, dn + dr), ("q_lora", "heads", "head_dim")),
+    s = {
         "wkv_a": Spec((d, rkv + dr), ("embed", "kv_lora")),
         "wk_b": Spec((rkv, h, dn), ("kv_lora", "heads", "head_dim")),
         "wv_b": Spec((rkv, h, dv), ("kv_lora", "heads", "head_dim")),
         "wo": Spec((h, dv, d), ("heads", "head_dim", "embed")),
-        "q_norm": Spec((rq,), ("q_lora",), init="ones"),
         "kv_norm": Spec((rkv,), ("kv_lora",), init="ones"),
     }
+    if rq:
+        s["wq_a"] = Spec((d, rq), ("embed", "q_lora"))
+        s["wq_b"] = Spec((rq, h, dn + dr), ("q_lora", "heads", "head_dim"))
+        s["q_norm"] = Spec((rq,), ("q_lora",), init="ones")
+    else:       # no query compression: a direct projection, no q_norm
+        s["wq"] = Spec((d, h, dn + dr), ("embed", "heads", "head_dim"))
+    return s
 
 
 def _mla_qk(cfg, p, x, positions):
     """Project to per-head q (nope|rope) and latent kv. x:(B,S,d)."""
     dn, dr = cfg.nope_head_dim, cfg.rope_head_dim
-    cq = common.rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), p["q_norm"],
-                         cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])          # (B,S,H,dn+dr)
+    if cfg.q_lora_rank:
+        cq = common.rms_norm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]),
+                             p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])      # (B,S,H,dn+dr)
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     kv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])           # (B,S,rkv+dr)

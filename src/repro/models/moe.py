@@ -1,5 +1,13 @@
-"""Dropped-token Mixture-of-Experts layer (Qwen3-MoE style: top-k softmax-
-renormalized gates, no shared expert).
+"""Mixture-of-Experts layers.
+
+``moe_apply`` (family "moe") is a capacity-factor layer that DROPS tokens
+past an expert's capacity (Qwen3-MoE style: top-k softmax-renormalized
+gates, no shared expert). ``held_moe_apply`` (family "mla_moe") is
+dropless and holds one chip's share of the experts (DeepSeek-V3 style:
+sigmoid scores with a selection bias, normalised top-k weights, shared
+experts); see below.
+
+Capacity layer:
 
 TPU-native dispatch: tokens are processed in groups of ``GROUP`` tokens; each
 group dispatches into per-expert capacity buffers with a deterministic
@@ -22,7 +30,8 @@ from repro.configs import ModelConfig
 from repro.dist.collectives import current_act_transport
 from repro.dist.sharding import constrain
 from repro.kernels.expert_a2a import expert_a2a
-from repro.models.common import Spec
+from repro.kernels.expert_gmm import expert_gmm
+from repro.models.common import Spec, swiglu
 
 GROUP = 512  # tokens per dispatch group (upper bound)
 
@@ -112,4 +121,93 @@ def moe_apply(cfg: ModelConfig, p, x: jnp.ndarray, mode: str = "train"
     dropped = 1.0 - jnp.mean(keep)
     aux = {"moe_lb_loss": lb_loss, "moe_z_loss": z_loss,
            "moe_drop_frac": dropped}
+    return y.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless layer holding one chip's share of the experts (family "mla_moe")
+# ---------------------------------------------------------------------------
+#
+# The router keeps the published width and routes every token over all
+# ``n_experts`` in float32. Of the chosen (token, choice) pairs, those whose
+# expert this chip holds (``expert_offset`` .. + ``experts_held`` - 1) go
+# through the ``expert_gmm`` grouped matmul, in proportion to their number:
+# no capacity, no dropped pair. Pairs routed to absent experts add nothing
+# here (in expert parallelism another chip computes them; on one chip the
+# layer runs without that exchange). The shared experts run on every token.
+
+HELD_COUNTERS = ("moe_held_pairs", "moe_max_expert_tokens")
+
+
+def add_counters(acc, new):
+    """``acc`` plus ``new``, key by key: counts add up (over layers,
+    over steps); ``moe_max_expert_tokens`` keeps the larger."""
+    return {k: jnp.maximum(acc[k], v)
+            if k == "moe_max_expert_tokens" and k in acc
+            else acc.get(k, 0) + v for k, v in new.items()}
+
+
+def held_moe_specs(cfg: ModelConfig) -> Dict[str, Spec]:
+    """One layer's router (every expert: it is on every chip), selection
+    bias, held experts and shared experts."""
+    d, e, eh, f = cfg.d_model, cfg.n_experts, cfg.n_held_experts, \
+        cfg.d_ff_expert
+    s = {
+        "router": Spec((d, e), ("embed", None)),
+        "router_bias": Spec((e,), (None,), init="zeros"),
+        "w_gate": Spec((eh, d, f), ("experts", "embed", "expert_mlp")),
+        "w_up": Spec((eh, d, f), ("experts", "embed", "expert_mlp")),
+        "w_down": Spec((eh, f, d), ("experts", "expert_mlp", "embed")),
+    }
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        s["shared"] = {"gate": Spec((d, fs), ("embed", "mlp")),
+                       "up": Spec((d, fs), ("embed", "mlp")),
+                       "down": Spec((fs, d), ("mlp", "embed"))}
+    return s
+
+
+def route(cfg: ModelConfig, router, bias, x2):
+    """Top-k experts of each token over all ``n_experts`` and their
+    weights, float32 (DeepSeek-V3's ``noaux_tc`` with one group): chosen
+    on sigmoid score + ``bias``, weighed by the unbiased scores over their
+    sum, times ``routed_scaling``. x2: (T, d) -> (sel (T, k) int32,
+    w (T, k))."""
+    logits = jnp.dot(x2.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.top_k)
+    w = jnp.take_along_axis(scores, sel, axis=-1)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * cfg.routed_scaling
+
+
+def held_moe_apply(cfg: ModelConfig, p, experts, x: jnp.ndarray, layer,
+                   mode: str = "train"):
+    """x: (B, S, d) -> (B, S, d), counters. ``p`` holds this layer's
+    router, bias and shared experts; ``experts`` the held experts' stacked
+    weights of every MoE layer (``w_gate``, ``w_up``: (L, E, d, f);
+    ``w_down``: (L, E, f, d)), of which ``layer`` is this one's index, so
+    that no layer's expert weights are sliced into a copy. Training runs
+    the grouped matmul's differentiable reference; prefill and decode the
+    kernel. Counters: pairs routed onto held experts, and the most tokens
+    one held expert took."""
+    b, s, d = x.shape
+    x2 = x.reshape(b * s, d)
+    sel, w = route(cfg, p["router"], p["router_bias"], x2)
+    # each pair's held expert (0 .. experts_held - 1), or -1 where another
+    # chip holds it
+    local = sel - cfg.expert_offset
+    groups = jnp.where((local >= 0) & (local < cfg.n_held_experts), local, -1)
+    y_pairs = expert_gmm(x2, groups, experts["w_gate"], experts["w_up"],
+                         experts["w_down"], layer, use_ref=mode == "train")
+    y = jnp.einsum("tk,tkd->td", w, y_pairs.astype(jnp.float32)
+                   ).astype(x.dtype)
+    if cfg.n_shared_experts:
+        sh = p["shared"]
+        y = y + swiglu(x2, sh["gate"], sh["up"], sh["down"])
+    counts = jnp.sum(groups.reshape(-1)[:, None]
+                     == jnp.arange(cfg.n_held_experts), 0)
+    aux = {"moe_held_pairs": jnp.sum(counts).astype(jnp.int32),
+           "moe_max_expert_tokens": jnp.max(counts).astype(jnp.int32)}
     return y.reshape(b, s, d), aux

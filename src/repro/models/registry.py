@@ -105,6 +105,7 @@ _FAMILY_CAPS = {
     "dense": _ATTENTION_CAPS,
     "moe": _ATTENTION_CAPS,
     "mla": _ATTENTION_CAPS,
+    "mla_moe": _ATTENTION_CAPS,
     "vlm": _ATTENTION_CAPS,
     "encoder_audio": _ATTENTION_CAPS,
     "hybrid": _RECURRENT_CAPS,

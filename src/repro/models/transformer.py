@@ -3,6 +3,11 @@
 Homogeneous stacks (dense / moe / mla / hybrid / encoder / vlm) store layer
 parameters with a leading ``layers`` axis and run under ``lax.scan`` with
 full rematerialization, so HLO size and activation memory are O(1) in depth.
+The "mla_moe" stack is not homogeneous (``first_k_dense`` leading dense
+layers, then held-share expert layers): its norms and attention are
+stacked over every layer and scanned as above, with one cache leaf per
+kind over all layers, while its FFN weights are two stacks of their own
+(``dense_mlp``, ``moe``) that each layer picks from by its index.
 xLSTM stacks are heterogeneous (alternating mLSTM/sLSTM) and use a Python
 loop (12 layers).
 
@@ -33,7 +38,8 @@ from repro.models.common import (
 VIT_HIDDEN = 1024    # stub InternViT output dim
 AUDIO_HIDDEN = 512   # stub conv-frontend output dim
 
-SCANNED_FAMILIES = ("dense", "moe", "mla", "hybrid", "encoder_audio", "vlm")
+SCANNED_FAMILIES = ("dense", "moe", "mla", "mla_moe", "hybrid",
+                    "encoder_audio", "vlm")
 
 
 # ---------------------------------------------------------------------------
@@ -43,7 +49,7 @@ SCANNED_FAMILIES = ("dense", "moe", "mla", "hybrid", "encoder_audio", "vlm")
 def layer_specs(cfg: ModelConfig) -> Dict[str, Any]:
     s: Dict[str, Any] = {"ln1": Spec((cfg.d_model,), ("embed",), init="ones"),
                          "ln2": Spec((cfg.d_model,), ("embed",), init="ones")}
-    if cfg.family == "mla":
+    if cfg.mla:
         s["attn"] = attention.mla_specs(cfg)
     else:
         s["attn"] = attention.gqa_specs(cfg)
@@ -51,13 +57,17 @@ def layer_specs(cfg: ModelConfig) -> Dict[str, Any]:
         s["ssm"] = ssm.ssm_specs(cfg)
     if cfg.family == "moe":
         s["moe"] = moe.moe_specs(cfg)
+    elif cfg.family == "mla_moe":
+        pass                             # FFN stacks of their own
     elif cfg.d_ff > 0:
-        s["mlp"] = {
-            "gate": Spec((cfg.d_model, cfg.d_ff), ("embed", "mlp")),
-            "up": Spec((cfg.d_model, cfg.d_ff), ("embed", "mlp")),
-            "down": Spec((cfg.d_ff, cfg.d_model), ("mlp", "embed")),
-        }
+        s["mlp"] = _mlp_specs(cfg)
     return s
+
+
+def _mlp_specs(cfg: ModelConfig) -> Dict[str, Spec]:
+    return {"gate": Spec((cfg.d_model, cfg.d_ff), ("embed", "mlp")),
+            "up": Spec((cfg.d_model, cfg.d_ff), ("embed", "mlp")),
+            "down": Spec((cfg.d_ff, cfg.d_model), ("mlp", "embed"))}
 
 
 def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
@@ -80,6 +90,12 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
             for i in range(cfg.n_layers)]
     else:
         specs["layers"] = stack_layer_specs(layer_specs(cfg), cfg.n_layers)
+    if cfg.family == "mla_moe":
+        if cfg.first_k_dense:
+            specs["dense_mlp"] = stack_layer_specs(_mlp_specs(cfg),
+                                                   cfg.first_k_dense)
+        specs["moe"] = stack_layer_specs(moe.held_moe_specs(cfg),
+                                         cfg.n_layers - cfg.first_k_dense)
     return specs
 
 
@@ -112,7 +128,7 @@ def cache_struct(cfg: ModelConfig, batch: int, seq: int,
              if xlstm.is_mlstm_layer(cfg, i)
              else xlstm.slstm_cache_shape(cfg, batch))
             for i in range(cfg.n_layers)]}
-    if cfg.family == "mla":
+    if cfg.mla:
         per = attention.mla_cache_shape(cfg, batch, seq)
     else:
         per = attention.gqa_cache_shape(cfg, batch, seq)
@@ -133,7 +149,7 @@ def _flat_cache_axes(cfg: ModelConfig) -> Dict[str, Any]:
     contributions (each family declares its per-layer leaf layout; the
     stack prepends "layers" and derives each quantization-scale leaf as
     its value leaf's layout with the trailing block axis unsharded)."""
-    if cfg.family == "mla":
+    if cfg.mla:
         per = attention.mla_cache_axes()
     else:
         per = attention.gqa_cache_axes()
@@ -225,8 +241,32 @@ def quantize_cache(cache: Dict[str, Any], kv_storage: str) -> Dict[str, Any]:
 # layer body (scanned families)
 # ---------------------------------------------------------------------------
 
+def _held_ffn(cfg: ModelConfig, ffn, h, layer, mode):
+    """The "mla_moe" FFN of layer ``layer`` (traced): the dense MLP of a
+    leading layer or the held-share expert layer, each indexed out of its
+    own stack. Returns (y, counters); a dense layer counts nothing."""
+    k = cfg.first_k_dense
+
+    def experts(h):
+        i = jnp.maximum(layer - k, 0)
+        p = {n: jax.tree.map(lambda t: t[i], v) for n, v in ffn["moe"].items()
+             if n not in ("w_gate", "w_up", "w_down")}
+        return moe.held_moe_apply(cfg, p, ffn["moe"], h, i, mode)
+
+    def dense(h):
+        p = jax.tree.map(lambda t: t[jnp.minimum(layer, k - 1)],
+                         ffn["dense_mlp"])
+        zero = jnp.zeros((), jnp.int32)
+        return (swiglu(h, p["gate"], p["up"], p["down"]),
+                {n: zero for n in moe.HELD_COUNTERS})
+
+    if not k:
+        return experts(h)
+    return jax.lax.cond(layer < k, dense, experts, h)
+
+
 def _layer_body(cfg: ModelConfig, mode: str, cache_len_total: int,
-                x, lp, lcache, pos):
+                x, lp, lcache, pos, layer=None, ffn=None):
     aux = {}
     # residual stream anchor; under the "sp"/"serve_sp" presets seq_res ->
     # model shards the residual stream (Megatron sequence parallelism)
@@ -243,7 +283,7 @@ def _layer_body(cfg: ModelConfig, mode: str, cache_len_total: int,
         attn_cache = lcache
     elif lcache is not None:
         attn_cache = {"k": lcache["k"], "v": lcache["v"]}
-    if cfg.family == "mla":
+    if cfg.mla:
         attn_out, new_attn = attention.mla_apply(
             cfg, lp["attn"], h, mode, attn_cache, pos, cache_len_total)
     else:
@@ -262,6 +302,8 @@ def _layer_body(cfg: ModelConfig, mode: str, cache_len_total: int,
         h2 = act_gather(h2, "batch", None, "act_embed")   # sp gather, MLP side
     if cfg.family == "moe":
         y, aux = moe.moe_apply(cfg, lp["moe"], h2, mode=mode)
+    elif cfg.family == "mla_moe":
+        y, aux = _held_ffn(cfg, ffn, h2, layer, mode)
     elif cfg.d_ff > 0:
         y = swiglu(h2, lp["mlp"]["gate"], lp["mlp"]["up"], lp["mlp"]["down"])
     else:
@@ -291,18 +333,23 @@ def _run_stack(cfg, params, x, mode, cache, pos, cache_len_total):
     n_units = cfg.n_layers // rb
     assert cfg.n_layers % rb == 0, (cfg.n_layers, rb)
 
-    def unit_body(xcur, lp_unit, lcache_unit, pos):
+    held = cfg.family == "mla_moe"
+    ffn = {k: params[k] for k in ("dense_mlp", "moe") if k in params} \
+        if held else None
+
+    def unit_body(xcur, lp_unit, lcache_unit, idx_unit=None, *, pos):
         caches = []
         aux_tot = {}
         for j in range(rb):
             lp = jax.tree.map(lambda t: t[j], lp_unit)
             lcache = jax.tree.map(lambda t: t[j], lcache_unit) \
                 if lcache_unit is not None else None
+            layer = None if idx_unit is None else idx_unit[j]
             xcur, new_lcache, aux = _layer_body(
-                cfg, mode, cache_len_total, xcur, lp, lcache, pos)
+                cfg, mode, cache_len_total, xcur, lp, lcache, pos,
+                layer, ffn)
             caches.append(new_lcache)
-            for k, v in (aux or {}).items():
-                aux_tot[k] = aux_tot.get(k, 0.0) + v
+            aux_tot = moe.add_counters(aux_tot, aux or {})
         if caches[0] is not None:
             caches = jax.tree.map(lambda *ts: jnp.stack(ts), *caches)
         else:
@@ -313,10 +360,8 @@ def _run_stack(cfg, params, x, mode, cache, pos, cache_len_total):
 
     def scan_fn(carry, xs):
         xcur, aux_acc = carry
-        lp_unit, lcache_unit = xs
-        xnew, new_lcache, aux = body(xcur, lp_unit, lcache_unit)
-        aux_acc = {k: aux_acc.get(k, 0.0) + v for k, v in aux.items()} \
-            if aux else aux_acc
+        xnew, new_lcache, aux = body(xcur, *xs)
+        aux_acc = moe.add_counters(aux_acc, aux) if aux else aux_acc
         return (xnew, aux_acc), new_lcache
 
     aux0 = {}
@@ -324,14 +369,18 @@ def _run_stack(cfg, params, x, mode, cache, pos, cache_len_total):
         aux0 = {"moe_lb_loss": jnp.zeros((), jnp.float32),
                 "moe_z_loss": jnp.zeros((), jnp.float32),
                 "moe_drop_frac": jnp.zeros((), jnp.float32)}
+    if held:
+        aux0 = {k: jnp.zeros((), jnp.int32) for k in moe.HELD_COUNTERS}
 
     def to_units(t):
         return t.reshape(n_units, rb, *t.shape[1:])
 
     lp_units = jax.tree.map(to_units, params["layers"])
     xs_cache = jax.tree.map(to_units, cache) if has_cache else None
-    (x, aux), new_cache = jax.lax.scan(scan_fn, (x, aux0),
-                                       (lp_units, xs_cache))
+    xs = (lp_units, xs_cache)
+    if held:            # each layer's index, to pick its FFN weights
+        xs += (to_units(jnp.arange(cfg.n_layers, dtype=jnp.int32)),)
+    (x, aux), new_cache = jax.lax.scan(scan_fn, (x, aux0), xs)
     if not emits_cache:
         new_cache = None
     elif new_cache is not None:
@@ -381,7 +430,9 @@ def _logits(cfg, params, x):
 
 
 def forward(cfg: ModelConfig, params, batch: Dict[str, Any], mode: str,
-            cache=None, cache_len_total: int = 0):
+            cache=None, cache_len_total: int = 0, return_aux: bool = False):
+    """``return_aux`` (decode): also return the stack's counters, e.g. the
+    held-share expert layer's ``moe.HELD_COUNTERS`` summed over layers."""
     x = _embed_inputs(cfg, params, batch, mode)
     pos = batch.get("pos", 0)
 
@@ -419,6 +470,8 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, Any], mode: str,
 
     # decode
     logits = _logits(cfg, params, x[:, -1])
+    if return_aux:
+        return logits, new_cache, aux
     return logits, new_cache
 
 

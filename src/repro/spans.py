@@ -6,7 +6,8 @@ thread's line of the trace, on the clock of the device's ``XLA Ops`` and
 runs. Capture them with ``jax.profiler.trace(dir)`` around a compaction
 cycle or a ``generate`` call and read them in Perfetto or TensorBoard.
 
-Every span name is defined here and nowhere else. A name never carries a
+Every span name, and the name of every kernel a per-layer metric reads
+from the device trace, is defined here and nowhere else. A name never carries a
 value: counts (files, bytes, request, step) are the span's arguments.
 ``lst/`` and ``core/`` import no JAX, so where JAX is not loaded ``span``
 returns a no-op context: nothing can be profiling that process.
@@ -45,6 +46,11 @@ SERVE_TRANSFER_WAIT = "serve.transfer_wait"
 SERVE_DECODE = "serve.decode"
 SERVE_SAMPLE = "serve.sample"
 SERVE_EMIT = "serve.emit"
+
+# Kernel names: the HLO instruction a device trace's ``XLA Ops`` line
+# shows for each named kernel (``<name>.<n>``)
+# kernels/expert_gmm: the held experts' grouped matmul
+KERNEL_EXPERT_GMM = "expert_gmm"
 
 _NO_SPAN = contextlib.nullcontext()
 

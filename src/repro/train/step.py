@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.configs import ModelConfig
 from repro.configs.shapes import ShapeSpec
 from repro.dist import collectives
+from repro.models import moe
 from repro.models import registry as model_registry
 from repro.models import transformer
 from repro.train import optimizer as opt_lib
@@ -239,14 +240,31 @@ def make_decode_step(cfg: ModelConfig, cache_len_total: int,
         model_registry.require(cfg, "quantized_storage",
                                f"kv_storage={kv_storage!r}")
 
-    def decode_step(params, cache, batch):
+    def decode_step(params, cache, batch, counters=None):
         with collectives.act_transport_scope(act_transport), \
                 collectives.kv_storage_scope(kv_storage):
-            logits, new_cache = transformer.forward(
+            if counters is None:
+                logits, new_cache = transformer.forward(
+                    cfg, params, batch, "decode", cache=cache,
+                    cache_len_total=cache_len_total)
+                return logits, new_cache
+            logits, new_cache, aux = transformer.forward(
                 cfg, params, batch, "decode", cache=cache,
-                cache_len_total=cache_len_total)
-        return logits, new_cache
+                cache_len_total=cache_len_total, return_aux=True)
+        return logits, new_cache, moe.add_counters(counters, aux)
     return decode_step
+
+
+def decode_counters(cfg: ModelConfig):
+    """Zeroed device-side counters a decode step of ``cfg`` accumulates
+    when passed them (``decode_step(params, cache, batch, counters)``),
+    or ``None`` where the stack counts nothing: the held-share expert
+    layer's pairs routed onto held experts (summed over layers and steps)
+    and the most tokens one held expert took in one step."""
+    if cfg.family != "mla_moe":
+        return None
+    return {k: jnp.zeros((), jnp.int32) for k in moe.HELD_COUNTERS}
+
 
 
 def step_for_shape(cfg: ModelConfig, shape: ShapeSpec,

@@ -330,7 +330,7 @@ class TestKernelKeys:
 
     def test_kernel_keys_are_gated_lower(self):
         for op in ("compact_pack", "flash_attn", "decode_attn",
-                   "paged_attn", "rmsnorm", "expert_a2a"):
+                   "paged_attn", "rmsnorm", "expert_a2a", "expert_gmm"):
             assert bench_diff.METRICS[f"kernel_{op}_tuned_s"] == "lower"
         assert bench_diff.METRICS["kernel_compact_filter_s"] == "lower"
         assert bench_diff.METRICS["kernel_compact_filter_hbm_bytes"] \

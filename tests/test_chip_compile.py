@@ -68,3 +68,29 @@ def test_fused_filter_compiles_at_deployment_size(one_chip):
         _i32(one_chip, n * CHUNK_ROWS), _i32(one_chip, n),
         _i32(one_chip, n), n_out=n).compile().as_text()
     assert hlo.count("tpu_custom_call") == math.ceil(n / FILTER_SEGMENT)
+
+
+@pytest.mark.parametrize("point", [
+    {"block_rows": 32, "block_ff": 128},        # the default
+    {"block_rows": 128, "block_ff": 1408},      # the largest blocks
+])
+def test_expert_gmm_compiles_at_deployment_size(one_chip, point):
+    """The held experts' grouped matmul at Moonlight-16B-A3B's decode
+    size: 128 tokens x top-6 over the stacked weights of 26 layers x 8
+    held experts (2048 x 1408), one Mosaic call named after the kernel,
+    with no copy of a layer's weights."""
+    from repro.kernels.expert_gmm import ops
+    from repro.spans import KERNEL_EXPERT_GMM
+
+    def sds(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    hlo = jax.jit(lambda *a: ops._run_jit(
+        *a, interpret=False, **point)).lower(
+        sds(bf16, 128, 2048), sds(jnp.int32, 128, 6),
+        sds(bf16, 26, 8, 2048, 1408), sds(bf16, 26, 8, 2048, 1408),
+        sds(bf16, 26, 8, 1408, 2048), sds(jnp.int32)).compile().as_text()
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].lstrip().startswith(f"%{KERNEL_EXPERT_GMM}.")
+    assert "bf16[8,2048,1408]" not in hlo

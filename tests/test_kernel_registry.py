@@ -49,7 +49,7 @@ class TestRegistry:
     def test_builtin_ops_registered(self):
         names = set(api.ops())
         assert {"compact_pack", "flash_attn", "decode_attn",
-                "paged_attn", "rmsnorm", "expert_a2a"} <= names
+                "paged_attn", "rmsnorm", "expert_a2a", "expert_gmm"} <= names
 
     def test_register_rejects_default_outside_candidates(self):
         bad = api.TunableOp(
@@ -109,7 +109,7 @@ class TestGridBitMatch:
 
     @pytest.mark.parametrize("name", ["compact_pack", "flash_attn",
                                       "decode_attn", "paged_attn",
-                                      "rmsnorm", "expert_a2a"])
+                                      "rmsnorm", "expert_a2a", "expert_gmm"])
     def test_every_grid_point_matches_ref(self, name):
         op = api.get_op(name)
         args, kwargs = op.example(True)

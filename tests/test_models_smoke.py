@@ -116,6 +116,7 @@ class TestFullConfigsAbstract:
         "hymba-1.5b": (1.5e9, 0.4),
         "internvl2-2b": (2e9, 0.25),
         "xlstm-125m": (125e6, 0.4),
+        "moonlight-16b-a3b": (16e9, 0.02),
     }
 
     @pytest.mark.parametrize("arch", ARCH_IDS)
