@@ -11,6 +11,11 @@ compaction time, in the same pass, via the fused filter+pack kernel
 through a second read. ``fused_filter=False`` routes the identical mask
 through the two-pass filter-then-pack reference instead; the outputs are
 bit-identical, only the HBM traffic differs.
+
+On the host each byte moves as few times as the device path allows: the
+inputs are read as views over the store's bytes and copied once, into
+the upload's concatenate; the merged shard is framed from views of the
+read-back in one join, so each output byte is written once.
 """
 
 from __future__ import annotations
@@ -69,6 +74,11 @@ def merge_shards_fn(table: LogStructuredTable, task: CompactionTask,
     valid boundary row that the mask keeps is kept verbatim, trailing pad
     included. Returns (DataFile, rows_dropped) — dropped counts only
     content rows the FILTER removed, not padding.
+
+    The inputs are views over the stored ``bytes`` (``decode_shard_padded``,
+    lengths from the headers); the output is ``shards.frame_shard`` of the
+    read-back's live slices (each fragment's true length, or the kept
+    prefix when filtered), one join that writes each output byte once.
     """
     with span(MERGE_SHARDS, inputs=len(task.inputs),
               input_bytes=int(task.input_bytes)):
@@ -78,7 +88,7 @@ def merge_shards_fn(table: LogStructuredTable, task: CompactionTask,
             for f in task.inputs:
                 raw = table.store.get(f.path)
                 payloads.append(sh.decode_shard_padded(raw))
-                lengths.append(len(sh.decode_shard(raw)))
+                lengths.append(sh.shard_length(raw))
         with span(MERGE_CONCAT):
             flat = np.concatenate(payloads) if payloads \
                 else np.zeros(0, np.int32)
@@ -98,28 +108,27 @@ def merge_shards_fn(table: LogStructuredTable, task: CompactionTask,
                 merged = np.asarray(compact_chunks(
                     jnp.asarray(flat), chunk_map, use_ref=not fused_filter,
                     keep_mask=keep))
-            tokens = merged[: int(keep.sum()) * CHUNK_COLS]
+            n_kept = int(keep.sum()) * CHUNK_COLS
             with span(MERGE_ENCODE):
-                raw = sh.encode_shard(tokens)
+                raw = sh.frame_shard([merged[:n_kept]])
             with span(MERGE_STORE):
                 table.store.put(out_path, raw)
             out = DataFile(path=out_path, size_bytes=len(raw),
-                           num_rows=int(tokens.shape[0]), partition=task.scope,
+                           num_rows=n_kept, partition=task.scope,
                            created_at=table.now_fn())
             return out, int(valid.sum() - keep.sum())
 
         with span(MERGE_DEVICE):
             merged = np.asarray(compact_chunks(jnp.asarray(flat), chunk_map))
-        # re-encode with the true concatenated length (drop inter-shard padding
-        # bookkeeping: lengths are tracked per fragment)
+        # each fragment's live tokens, as views of the read-back: the
+        # inter-fragment padding is dropped by framing only these
         with span(MERGE_RESLICE):
-            tokens = np.concatenate([
-                merged[sum(c * CHUNK_TOKENS for c in counts[:i]):][:lengths[i]]
-                for i in range(len(counts))]) if counts else merged[:0]
+            starts = np.cumsum([0] + counts[:-1]) * CHUNK_TOKENS
+            live = [merged[s: s + n] for s, n in zip(starts, lengths)]
         with span(MERGE_ENCODE):
-            raw = sh.encode_shard(tokens)
+            raw = sh.frame_shard(live)
         with span(MERGE_STORE):
             table.store.put(out_path, raw)
         return DataFile(path=out_path, size_bytes=len(raw),
-                        num_rows=int(tokens.shape[0]), partition=task.scope,
+                        num_rows=sum(lengths), partition=task.scope,
                         created_at=table.now_fn())

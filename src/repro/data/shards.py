@@ -2,8 +2,12 @@
 
 A shard is an int32 token array padded to CHUNK_TOKENS (1024) alignment —
 the alignment contract that turns compaction into the chunk-permutation DMA
-kernel (repro.kernels.compact_pack). The header records the true
-(pre-padding) length.
+kernel (repro.kernels.compact_pack). The 12-byte header (magic, int64)
+records the true (pre-padding) length.
+
+The codec copies nothing it need not: a decode is a read-only view over
+the stored ``bytes``, and ``frame_shard`` writes a shard's bytes once,
+in one join of the header, the token arrays' buffers and the zero pad.
 
 Writers model the paper's §2 causes of small files:
   * TrickleWriter — CDC/streaming ingestion: many small appends;
@@ -24,6 +28,7 @@ from repro.lst.files import DataFile
 from repro.lst.table import LogStructuredTable
 
 _MAGIC = b"TOKS"
+_HEADER = struct.Struct("<4sq")     # magic, true length
 
 
 def zipf_tokens(rng: np.random.RandomState, vocab: int, n: int) -> np.ndarray:
@@ -33,25 +38,40 @@ def zipf_tokens(rng: np.random.RandomState, vocab: int, n: int) -> np.ndarray:
     return ((vals - 1) % vocab).astype(np.int32)
 
 
-def encode_shard(tokens: np.ndarray) -> bytes:
-    tokens = np.asarray(tokens, dtype=np.int32)
-    n = tokens.shape[0]
+def frame_shard(parts: Sequence[np.ndarray]) -> bytes:
+    """The shard of the int32 ``parts`` end to end: the header, each
+    part's buffer and the zero pad to the next chunk, in one join."""
+    n = sum(int(p.shape[0]) for p in parts)
     pad = (-n) % CHUNK_TOKENS
-    padded = np.concatenate([tokens, np.zeros(pad, np.int32)]) if pad else tokens
-    return _MAGIC + struct.pack("<q", n) + padded.tobytes()
+    return b"".join([_HEADER.pack(_MAGIC, n),
+                     *(memoryview(np.ascontiguousarray(p, np.int32))
+                       for p in parts),
+                     bytes(4 * pad)])
+
+
+def encode_shard(tokens: np.ndarray) -> bytes:
+    """One token array as a shard."""
+    return frame_shard([tokens])
+
+
+def shard_length(raw: bytes) -> int:
+    """True (pre-padding) token count, from the header."""
+    magic, n = _HEADER.unpack_from(raw)
+    assert magic == _MAGIC, "not a token shard"
+    return n
 
 
 def decode_shard(raw: bytes) -> np.ndarray:
-    assert raw[:4] == _MAGIC, "not a token shard"
-    (n,) = struct.unpack("<q", raw[4:12])
-    arr = np.frombuffer(raw[12:], dtype=np.int32)
-    return arr[:n]
+    """The tokens: a read-only view over ``raw``."""
+    return np.frombuffer(raw, np.int32, count=shard_length(raw),
+                         offset=_HEADER.size)
 
 
 def decode_shard_padded(raw: bytes) -> np.ndarray:
-    """Full chunk-aligned payload including padding (kernel input)."""
-    assert raw[:4] == _MAGIC
-    return np.frombuffer(raw[12:], dtype=np.int32)
+    """Full chunk-aligned payload including padding (kernel input): a
+    read-only view over ``raw``."""
+    shard_length(raw)                   # checks the magic
+    return np.frombuffer(raw, np.int32, offset=_HEADER.size)
 
 
 @dataclasses.dataclass

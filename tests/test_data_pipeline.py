@@ -2,6 +2,8 @@
 determinism, and the central invariant — compaction NEVER changes the token
 multiset the training job reads."""
 
+import struct
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -12,7 +14,17 @@ from repro.data.shards import decode_shard_padded
 from repro.kernels.compact_pack.compact_pack import CHUNK_TOKENS
 from repro.lst import Catalog, InMemoryStore
 from repro.lst import compaction as comp
+from repro.lst.compaction import CompactionTask
+from repro.lst.files import DataFile
 from repro.lst.workload import SimClock
+
+
+def shard_layout(tokens):
+    """A shard's bytes as the format defines them, built independently of
+    the codec: magic, int64 true length, the tokens, zeros to the chunk."""
+    pad = (-tokens.size) % CHUNK_TOKENS
+    return (b"TOKS" + struct.pack("<q", tokens.size)
+            + np.concatenate([tokens, np.zeros(pad, np.int32)]).tobytes())
 
 
 def make_table(seed=0):
@@ -38,12 +50,76 @@ class TestShardFormat:
         assert padded.shape[0] % CHUNK_TOKENS == 0
         assert padded.shape[0] >= n
 
+    @pytest.mark.parametrize("n", [0, 1, 1023, 1024, 1025])
+    def test_encode_is_the_shard_layout(self, n):
+        toks = np.random.RandomState(n).randint(
+            -(1 << 31), 1 << 31, size=n).astype(np.int32)
+        raw = encode_shard(toks)
+        assert type(raw) is bytes
+        assert raw == shard_layout(toks)
+
+    @pytest.mark.parametrize("n", [0, 1, 1024, 1025])
+    def test_decode_is_a_view_of_the_stored_bytes(self, n):
+        store = InMemoryStore()
+        toks = np.arange(n, dtype=np.int32)
+        store.put("s.toks", encode_shard(toks))
+        raw = store.get("s.toks")
+        out, padded = decode_shard(raw), decode_shard_padded(raw)
+        assert out.base is raw and padded.base is raw
+        assert not out.flags.writeable and not padded.flags.writeable
+        assert out.shape == (n,)
+        assert padded.shape == (-(-n // CHUNK_TOKENS) * CHUNK_TOKENS,)
+        assert np.array_equal(out, toks)
+        assert not padded[n:].any()
+
     def test_pack_tokens_shapes_and_labels(self):
         stream = np.arange(4 * 3 * 9 + 5, dtype=np.int32)
         slabs = pack_tokens(stream, batch=3, seq_len=8)
         assert slabs.shape == (4, 3, 9)
         # labels are next-token shifted views of the same stream
         assert np.array_equal(slabs[0, 0, 1:], stream[1:9])
+
+
+class TestMergeOutputBytes:
+    """The merged shard equals, byte for byte, the shard layout of the
+    tokens the merge should keep, computed here from the inputs alone."""
+
+    @staticmethod
+    def _keep_odd(rows, task):
+        return (rows[:, 0] % 2).astype(bool)
+
+    @pytest.mark.parametrize("filtered", [False, True],
+                             ids=["plain", "filtered"])
+    def test_merge_output_is_the_layout_of_the_kept_tokens(self, filtered):
+        _, table, store = make_table()
+        rng = np.random.RandomState(11)
+        files, inputs = [], []
+        for j, n in enumerate([1, 1023, 1024, 1025, 3000, 0, 129]):
+            toks = rng.randint(0, 997, size=n).astype(np.int32)
+            path = f"{table.table_id}/data/s{j}.toks"
+            store.put(path, shard_layout(toks))
+            files.append(DataFile(path=path, size_bytes=CHUNK_TOKENS,
+                                  num_rows=n, created_at=0.0))
+            inputs.append(toks)
+        table.append(files)
+        task = CompactionTask(task_id=0, table_id=table.table_id,
+                              scope=None, inputs=tuple(files),
+                              est_output_bytes=0)
+        if filtered:
+            out, dropped = merge_shards_fn(table, task, "out.toks",
+                                           filter_fn=self._keep_odd)
+            rows = [np.concatenate([t, np.zeros((-t.size) % CHUNK_TOKENS,
+                                                np.int32)]).reshape(-1, 128)
+                    [:-(-t.size // 128)] for t in inputs]
+            rows = np.concatenate(rows)
+            want = rows[rows[:, 0] % 2 == 1].reshape(-1)
+            assert dropped == int((rows[:, 0] % 2 == 0).sum()) > 0
+        else:
+            out = merge_shards_fn(table, task, "out.toks")
+            want = np.concatenate(inputs)
+        raw = store.get("out.toks")
+        assert raw == shard_layout(want)
+        assert out.size_bytes == len(raw) and out.num_rows == want.size
 
 
 class TestCompactionPreservesData:
