@@ -41,27 +41,33 @@ Continuous batching: requests at different positions share one decode step
 padded prompt slots are never attended — same semantics the decode_attn
 Pallas kernel implements on TPU).
 
-``--workers N`` (or ``--paged``) routes serving through the *fan-in*
-engine (:func:`_generate_fanin`): N independent prefill workers — each
-running the same double-buffered mover — feed ONE decode slot table
-through :class:`repro.dist.fanin.AdmissionArbiter` (FIFO with priority
-classes, aging + hard promotion mirroring the fleet scheduler's
-starvation bound, per-worker in-flight accounting, and a deterministic
-tie-break: the engine blocks on the arbiter's chosen shipment instead of
-racing worker completion order, so admissions replay identically under
-permuted arrival). When the table is full, ``--evict`` preempts a
-justified victim — the evicted request requeues with its emitted tokens
-appended to its prompt and is re-prefilled on readmission (recompute
-preemption; greedy tokens bit-match an uncontended run). ``--paged``
-swaps the dense pad-to-horizon slot table for a *paged* one
+There is one slot engine (:func:`_generate_slots`); its admission order is
+owned by :class:`repro.dist.fanin.AdmissionArbiter`, and ``--workers``,
+``--evict`` and ``--paged`` are its configuration. The plain slot stream
+is one prefill worker, one priority class and no eviction: FIFO into the
+lowest free slot with one shipment prefetched. ``--workers N`` (or
+``--paged``) makes it the *fan-in* configuration: N independent prefill
+workers — each running the same double-buffered mover — feed the ONE
+decode slot table through the arbiter (FIFO with priority classes, aging
++ hard promotion mirroring the fleet scheduler's starvation bound,
+per-worker in-flight accounting, and a deterministic tie-break: the
+engine blocks on the arbiter's chosen shipment instead of racing worker
+completion order, so admissions replay identically under permuted
+arrival). When the table is full, ``--evict`` preempts a justified victim
+— the evicted request requeues with its emitted tokens appended to its
+prompt and is re-prefilled on readmission (recompute preemption; greedy
+tokens bit-match an uncontended run). ``--paged`` swaps the dense
+pad-to-horizon slot table for a *paged* one
 (:class:`repro.models.registry.PagedStateStore`): rows are lists of
 fixed-size pages in a shared pool with a per-slot page table, admission
 ships only live pages, pages allocate on demand as a row decodes past a
 page boundary, and the decode step runs *unchanged* on a dense view
 gathered through the table (bit parity with the unpaged path). The page
 size is a tunable axis on the kernel registry (``paged_attn``), swept by
-``tune_design`` like every other kernel block. See docs/serving.md for
-the full operator's guide.
+``tune_design`` like every other kernel block. :class:`_Programs` builds
+the prefill and decode programs, the parameter placements, the cache
+mover and the zero cache, for the slot engine and the whole-batch body
+alike. See docs/serving.md for the full operator's guide.
 """
 
 from __future__ import annotations
@@ -89,40 +95,27 @@ from repro.train import step as step_lib
 
 
 def grow_cache(cache, target):
-    """Grow every cache leaf to the decode-horizon shape (end-padding).
+    """Fit every cache leaf to the target shape: end-padding with zeros.
 
     ``target`` is the abstract decode cache, so windowed/SSM/xLSTM states
     are handled uniformly: leaves already at the target shape only cast,
     anything smaller pads with zeros at the end of each dimension (new
     slots read as empty and are masked by slot-position validity until
-    written).
+    written). A leaf *longer* than the target is first cut to it: a paged
+    admission ships ``ceil(len / page)`` pages, which may be fewer
+    positions than the ``[1, S0]`` prefill buffer (the dropped tail is
+    pad junk beyond the request's live length, which per-row position
+    masks never attend).
     """
     def grow(c, tgt):
         if c.shape == tgt.shape:
             return c.astype(tgt.dtype)
+        if any(s > t for s, t in zip(c.shape, tgt.shape)):
+            c = c[tuple(slice(0, t) for t in tgt.shape)]
         pad = [(0, t - s) for s, t in zip(c.shape, tgt.shape)]
         return jnp.pad(c, pad).astype(tgt.dtype)
 
     return jax.tree.map(grow, cache, target)
-
-
-def fit_cache(cache, target):
-    """:func:`grow_cache` that can also *shrink*: every leaf is sliced to
-    the target extent before padding. The fan-in engine needs both
-    directions — a fresh paged admission ships ``ceil(len / page)`` pages,
-    which may be fewer positions than the ``[1, S0]`` prefill buffer
-    (the dropped tail is pad junk beyond the request's live length, which
-    per-row position masks never attend), while a readmitted request's
-    exact-length prefill pads up to the next page boundary.
-    """
-    def fit(c, tgt):
-        if c.shape == tgt.shape:
-            return c.astype(tgt.dtype)
-        c = c[tuple(slice(0, min(s, t)) for s, t in zip(c.shape, tgt.shape))]
-        pad = [(0, t - s) for s, t in zip(c.shape, tgt.shape)]
-        return jnp.pad(c, pad).astype(tgt.dtype)
-
-    return jax.tree.map(fit, cache, target)
 
 
 def make_cache_transfer_step(cfg, batch: int, total: int, mode: str,
@@ -218,6 +211,158 @@ def make_cache_mover(cfg, batch: int, total: int, dec_mesh, dec_rules,
     return move
 
 
+class _Programs:
+    """The serving programs of one ``generate`` call, built in one place
+    for the whole-batch body and the slot engine.
+
+    Resolves the meshes and rules (``rules`` defaults to ``serve_sp`` on
+    ``mesh``; ``decode_mesh`` disaggregates, ``decode_rules`` defaulting
+    to ``serve_decode``), places ``params`` once per distinct prefill mesh
+    (``prefill_meshes``, one entry per prefill worker; default
+    ``[mesh]``) and, disaggregated, once more on the decode mesh, and
+    jits one ``prefill_step`` per distinct prefill mesh and one
+    ``decode_step`` over a ``rows``-row cache of ``total`` positions in
+    its ``kv_storage`` layout (pool-form through ``paged_store``'s page
+    table when given), with the cache's decode-mesh shardings as its
+    output layout. ``counters`` says whether the decode step is handed
+    ``step_lib.decode_counters`` (and returns them). :meth:`grow`,
+    :meth:`mover` and :meth:`init_cache` build the slice programs, the
+    cross-mesh handoff and the zero cache on demand.
+    """
+
+    def __init__(self, cfg, params, rows: int, total: int, *, mesh, rules,
+                 act_transport: str, decode_mesh, decode_rules,
+                 cache_transfer: str, kv_storage: str,
+                 prefill_meshes=None, paged_store=None,
+                 counters: bool = False):
+        if cache_transfer not in collectives.CACHE_TRANSFERS:
+            raise ValueError(f"unknown cache_transfer {cache_transfer!r}; "
+                             f"expected one of {collectives.CACHE_TRANSFERS}")
+        meshes = [mesh] if prefill_meshes is None else list(prefill_meshes)
+        if mesh is None:
+            mesh = meshes[0]
+        self.disagg = decode_mesh is not None
+        if self.disagg and mesh is None:
+            raise ValueError("disaggregated serving (decode_mesh=...) needs "
+                             "a prefill mesh too")
+        if mesh is not None and rules is None:
+            rules = shd.PRESETS["serve_sp"]
+        if self.disagg and decode_rules is None:
+            decode_rules = shd.PRESETS["serve_decode"]
+        self.cfg, self.cache_transfer = cfg, cache_transfer
+        self.dec_mesh = decode_mesh if self.disagg else mesh
+        self.dec_rules = decode_rules if self.disagg else rules
+        # Under the serve_decode preset the cache is resident — decode has
+        # no per-step gather to compress, so an int8 act transport there
+        # would only round the whole resident cache through s8 every step
+        # (logit drift, extra compute, zero wire saved). Drop to bf16 for
+        # the decode half; custom decode_rules keep the caller's choice.
+        dec_act = "bf16" if self.disagg and \
+            self.dec_rules is shd.PRESETS["serve_decode"] else act_transport
+        # validates kv_storage (and the family's eligibility for int8)
+        decode_fn = step_lib.make_decode_step(cfg, total, dec_act,
+                                              kv_storage)
+
+        self.pre_ctx = [shd.axis_rules(m, rules) if m is not None
+                        else contextlib.nullcontext() for m in meshes]
+        self.dec_ctx = shd.axis_rules(self.dec_mesh, self.dec_rules) \
+            if self.dec_mesh is not None else contextlib.nullcontext()
+
+        def place(m, r):
+            return params if m is None else jax.device_put(
+                params, shd.tree_shardings(transformer.abstract_params(cfg),
+                                           transformer.param_axes(cfg),
+                                           m, r))
+        # One prefill_step per DISTINCT prefill mesh: ``constrain`` bakes
+        # the trace-time mesh into the jaxpr and jit reuses traces by
+        # aval alone, so a shared jit would replay worker 0's sharding
+        # constraints on worker 1's devices (incompatible-devices error on
+        # the first cross-worker call). The trace cache is keyed on the
+        # wrapped function object, so each mesh gets its own function
+        # from make_prefill_step, not just its own jit wrapper.
+        per_mesh = {}
+        for m in meshes:
+            if id(m) not in per_mesh:
+                per_mesh[id(m)] = (place(m, rules), jax.jit(
+                    step_lib.make_prefill_step(cfg, act_transport)))
+        self.params_pre = [per_mesh[id(m)][0] for m in meshes]
+        self.prefill = [per_mesh[id(m)][1] for m in meshes]
+        # the decode cluster holds its own replica of the weights
+        self.params_dec = place(self.dec_mesh, self.dec_rules) \
+            if self.disagg else self.params_pre[0]
+
+        if paged_store is not None:
+            self.state_abs = paged_store.abstract_state()
+            state_axes = paged_store.state_axes()
+            decode_fn = _paged_decode_step(paged_store, decode_fn)
+        else:
+            self.state_abs = transformer.abstract_cache(
+                cfg, rows, total, kv_storage=kv_storage)
+            state_axes = transformer.cache_axes(cfg, rows, total,
+                                                kv_storage=kv_storage)
+        self.c_shard = None
+        if self.dec_mesh is not None:
+            self.c_shard = shd.tree_shardings(self.state_abs, state_axes,
+                                              self.dec_mesh, self.dec_rules)
+            out = (None, self.c_shard) + ((None,) if counters else ())
+            self.decode = jax.jit(decode_fn, out_shardings=out)
+        else:
+            self.decode = jax.jit(decode_fn)
+        self._grow, self._movers = {}, {}
+
+    def grow(self, width: int):
+        """Jitted :func:`grow_cache` of a one-request prefill cache to a
+        ``[1, width]`` slice (one program per width; the device trace
+        names it ``grow_cache``)."""
+        if width not in self._grow:
+            target = transformer.abstract_cache(self.cfg, 1, width)
+
+            def grow(c):
+                return grow_cache(c, target)
+            grow.__name__ = "grow_cache"
+            self._grow[width] = jax.jit(grow)
+        return self._grow[width]
+
+    def cache_shardings(self, rows: int, width: int):
+        """The bf16 ``[rows, width]`` cache's shardings on the decode
+        mesh."""
+        return shd.tree_shardings(
+            transformer.abstract_cache(self.cfg, rows, width),
+            transformer.cache_axes(self.cfg, rows, width),
+            self.dec_mesh, self.dec_rules)
+
+    def mover(self, rows: int, width: int):
+        """:func:`make_cache_mover` for a ``[rows, width]`` bf16 cache
+        onto the decode mesh, built once per shape."""
+        if (rows, width) not in self._movers:
+            self._movers[rows, width] = make_cache_mover(
+                self.cfg, rows, width, self.dec_mesh, self.dec_rules,
+                self.cache_transfer, self.cache_shardings(rows, width))
+        return self._movers[rows, width]
+
+    def init_cache(self):
+        """The zero cache in its decode-mesh layout."""
+        state_abs = self.state_abs
+
+        def init_cache():
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                state_abs)
+        return jax.jit(init_cache, out_shardings=self.c_shard)()
+
+
+def _paged_decode_step(store, decode_fn):
+    """The unchanged dense ``decode_fn`` bracketed by ``store``'s gather
+    and scatter through the page table, which rides in the batch as
+    ``"page_table"``."""
+    def paged_decode_step(params, pool, batch, counters=None):
+        batch = dict(batch)
+        pt = batch.pop("page_table")
+        logits, dense, *rest = decode_fn(
+            params, store.gather_dense(pool, pt), batch, counters)
+        return (logits, store.scatter_dense(pool, dense, pt), *rest)
+    return paged_decode_step
+
+
 STREAMS = ("batch", "slots")
 
 
@@ -309,45 +454,38 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
     double-buffered behind the current decode steps — see
     :func:`_generate_slots`.
 
-    ``workers > 1`` or ``paged=True`` routes through the fan-in engine
-    (:func:`_generate_fanin`): ``workers`` prefill workers (optionally on
-    their own meshes via ``prefill_meshes``) feed the slot table through
-    the admission arbiter; ``evict`` picks the preemption policy,
-    ``priorities`` (B,) assigns admission classes (0 = most urgent), and
+    ``workers > 1``, ``paged=True`` or ``prefill_meshes`` serves through
+    the slot engine's fan-in configuration, whatever ``stream`` says:
+    ``workers`` prefill workers (optionally on their own meshes via
+    ``prefill_meshes``) feed the slot table through the admission
+    arbiter; ``evict`` picks the preemption policy, ``priorities`` (B,)
+    assigns admission classes (0 = most urgent), and
     ``paged``/``page_size``/``pool_pages`` swap in the paged slot cache.
     ``horizon`` caps the decode horizon in positions (0 = sized to fit):
     an unpaged request that cannot fit is refused loudly, never silently
     truncated; a paged one admits.
 
-    ``keep_logits=True`` (batch and slot streams) keeps the logit row each
-    generated token was chosen from, on the device until the call
-    returns, and leaves them as ``last_stats["logits"]``, float32
-    ``(B, max_new, vocab)``: what a caller checks the serving numerics
-    against. Off, the loop keeps nothing.
+    ``keep_logits=True`` (the batch stream and the plain slot stream)
+    keeps the logit row each generated token was chosen from, on the
+    device until the call returns, and leaves them as
+    ``last_stats["logits"]``, float32 ``(B, max_new, vocab)``: what a
+    caller checks the serving numerics against. Off, the loop keeps
+    nothing.
     """
     if stream not in STREAMS:
         raise ValueError(f"unknown stream {stream!r}; "
                          f"expected one of {STREAMS}")
-    if workers > 1 or paged or prefill_meshes is not None:
-        if keep_logits:
-            raise ValueError("keep_logits: batch and slot streams only")
-        return _generate_fanin(
-            cfg, params, prompts, max_new=max_new, temperature=temperature,
-            seed=seed, prompt_lens=prompt_lens, mesh=mesh, rules=rules,
-            act_transport=act_transport, decode_mesh=decode_mesh,
-            decode_rules=decode_rules, cache_transfer=cache_transfer,
-            kv_storage=kv_storage, slots=slots, workers=workers,
-            evict=evict, paged=paged, page_size=page_size,
-            pool_pages=pool_pages, horizon=horizon, priorities=priorities,
-            prefill_meshes=prefill_meshes)
-    if stream == "slots":
+    if stream == "slots" or workers > 1 or paged or \
+            prefill_meshes is not None:
         return _generate_slots(
             cfg, params, prompts, max_new=max_new, temperature=temperature,
             seed=seed, prompt_lens=prompt_lens, mesh=mesh, rules=rules,
             act_transport=act_transport, decode_mesh=decode_mesh,
             decode_rules=decode_rules, cache_transfer=cache_transfer,
             kv_storage=kv_storage, slots=slots, horizon=horizon,
-            keep_logits=keep_logits)
+            keep_logits=keep_logits, workers=workers, evict=evict,
+            paged=paged, page_size=page_size, pool_pages=pool_pages,
+            priorities=priorities, prefill_meshes=prefill_meshes)
     b, s0 = prompts.shape
     total = s0 + max_new
     ragged = prompt_lens is not None
@@ -362,86 +500,29 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
         # undo either. row_state families serve mixed lengths through
         # --stream slots (exact-length per-request prefill) instead.
         registry.require(cfg, "ragged", "ragged prompt_lens")
-    if cache_transfer not in collectives.CACHE_TRANSFERS:
-        raise ValueError(f"unknown cache_transfer {cache_transfer!r}; "
-                         f"expected one of {collectives.CACHE_TRANSFERS}")
+    progs = _Programs(cfg, params, b, total, mesh=mesh, rules=rules,
+                      act_transport=act_transport, decode_mesh=decode_mesh,
+                      decode_rules=decode_rules,
+                      cache_transfer=cache_transfer, kv_storage=kv_storage)
 
-    disagg = decode_mesh is not None
-    if disagg and mesh is None:
-        raise ValueError("disaggregated serving (decode_mesh=...) needs a "
-                         "prefill mesh too")
-    if mesh is not None and rules is None:
-        rules = shd.PRESETS["serve_sp"]
-    if disagg and decode_rules is None:
-        decode_rules = shd.PRESETS["serve_decode"]
-    dec_mesh = decode_mesh if disagg else mesh
-    dec_rules = decode_rules if disagg else rules
-
-    prefill_fn = step_lib.make_prefill_step(cfg, act_transport)
-    # Under the serve_decode preset the cache is resident — decode has no
-    # per-step gather to compress, so an int8 act transport there would
-    # only round the whole resident cache through s8 every step (logit
-    # drift, extra compute, zero wire saved). Drop to bf16 for the decode
-    # half; custom decode_rules keep the caller's choice.
-    dec_act = "bf16" if disagg and dec_rules is shd.PRESETS["serve_decode"] \
-        else act_transport
-    # validates kv_storage (and the family's eligibility for int8)
-    decode_fn = step_lib.make_decode_step(cfg, total, dec_act, kv_storage)
-
-    pre_ctx = shd.axis_rules(mesh, rules) if mesh is not None \
-        else contextlib.nullcontext()
-    dec_ctx = shd.axis_rules(dec_mesh, dec_rules) if dec_mesh is not None \
-        else contextlib.nullcontext()
-
-    c_abs_bf16 = transformer.abstract_cache(cfg, b, total)
-
-    with pre_ctx:
-        params_pre = params
-        if mesh is not None:
-            p_shard = shd.tree_shardings(transformer.abstract_params(cfg),
-                                         transformer.param_axes(cfg),
-                                         mesh, rules)
-            params_pre = jax.device_put(params, p_shard)
-        prefill = jax.jit(prefill_fn)
+    with progs.pre_ctx[0]:
         pre_batch = {"tokens": jnp.asarray(prompts)}
         if ragged:
             pre_batch["last_pos"] = jnp.asarray(lens - 1)
-        logits, cache = prefill(params_pre, pre_batch)
-        cache = grow_cache(cache, c_abs_bf16)
+        logits, cache = progs.prefill[0](progs.params_pre[0], pre_batch)
+        cache = grow_cache(cache, transformer.abstract_cache(cfg, b, total))
 
-    # ---- handoff: place the grown cache (and params) on the decode side
-    with dec_ctx:
-        c_shard = None
-        params_dec = params_pre
-        if dec_mesh is not None:
-            c_axes = transformer.cache_axes(cfg, b, total)
-            dst = shd.tree_shardings(c_abs_bf16, c_axes, dec_mesh, dec_rules)
-            c_shard = dst
-            if kv_storage != "bf16":
-                c_shard = shd.tree_shardings(
-                    transformer.abstract_cache(cfg, b, total,
-                                               kv_storage=kv_storage),
-                    transformer.cache_axes(cfg, b, total,
-                                           kv_storage=kv_storage),
-                    dec_mesh, dec_rules)
-            if disagg:
-                # the decode cluster holds its own replica of the weights
-                p_shard_dec = shd.tree_shardings(
-                    transformer.abstract_params(cfg),
-                    transformer.param_axes(cfg), dec_mesh, dec_rules)
-                params_dec = jax.device_put(params, p_shard_dec)
-                cache = make_cache_mover(cfg, b, total, dec_mesh,
-                                         dec_rules, cache_transfer,
-                                         dst)(cache)
-            else:
-                # colocated: commit the grown cache to its serve placement
-                cache = jax.device_put(cache, dst)
+    # ---- handoff: place the grown cache on the decode side
+    with progs.dec_ctx:
+        if progs.disagg:
+            cache = progs.mover(b, total)(cache)
+        elif progs.dec_mesh is not None:
+            # colocated: commit the grown cache to its serve placement
+            cache = jax.device_put(cache, progs.cache_shardings(b, total))
         if kv_storage != "bf16":
             quant = jax.jit(lambda c: transformer.quantize_cache(
-                c, kv_storage), out_shardings=c_shard)
+                c, kv_storage), out_shardings=progs.c_shard)
             cache = quant(cache)
-        decode = jax.jit(decode_fn, out_shardings=(None, c_shard)) \
-            if c_shard is not None else jax.jit(decode_fn)
 
         # first sampled token comes from prefill logits — the one batch
         # tensor that crosses from the prefill to the decode mesh
@@ -456,8 +537,8 @@ def generate(cfg, params, prompts: np.ndarray, max_new: int = 16,
                 kept.append(logits)
             pos = jnp.asarray(lens + i) if ragged \
                 else jnp.asarray(s0 + i, jnp.int32)
-            logits, cache = decode(params_dec, cache,
-                                   {"tokens": tok, "pos": pos})
+            logits, cache = progs.decode(progs.params_dec, cache,
+                                         {"tokens": tok, "pos": pos})
             if temperature > 0:
                 key, sub = jax.random.split(key)
                 tok = jax.random.categorical(sub, logits / temperature
@@ -520,9 +601,14 @@ def _generate_slots(cfg, params, prompts: np.ndarray, max_new: int,
                     mesh, rules, act_transport: str,
                     decode_mesh, decode_rules,
                     cache_transfer: str, kv_storage: str, slots: int,
-                    horizon: int = 0, keep_logits: bool = False):
-    """Continuous cross-batch disaggregation: prefill streams each
-    finished request's cache slice into a RUNNING decode batch.
+                    horizon: int = 0, keep_logits: bool = False,
+                    workers: int = 1, evict: str = "none",
+                    paged: bool = False, page_size: int = 0,
+                    pool_pages: int = 0,
+                    priorities: Optional[np.ndarray] = None,
+                    prefill_meshes=None):
+    """The slot engine: prefill streams each finished request's cache
+    slice into a RUNNING decode batch.
 
     The decode side holds a slot table of ``slots`` rows (the state's
     batch dim doubles as the slot dim). Each request is prefilled on its
@@ -532,217 +618,338 @@ def _generate_slots(cfg, params, prompts: np.ndarray, max_new: int,
     quantized/shipped/dequantized into a free slot
     (:func:`make_slot_admit_step`, a :class:`~repro.models.registry.\
 StateStore` row write), and the slot decodes from the request's own
-    position while other slots are mid-decode or still empty. A finished slot is freed and reused by the next pending
-    request — admission overwrites the entire ``[1, total]`` row, so no
-    state can bleed between consecutive occupants. Transfers are
-    double-buffered: the next pending request's prefill + wire shipment
-    is dispatched (async) at admission time, so it overlaps the decode
-    steps that run before the next slot frees; the wall-clock wait the
-    overlap failed to hide is recorded in ``_generate_slots.last_stats``
-    (the launcher prints it), with the kept logit rows under
-    ``keep_logits`` (see :func:`generate`). A configuration whose decode
-    step counts (``step_lib.decode_counters``: the held-share expert
-    layer's ``moe_held_pairs`` and ``moe_max_expert_tokens``, over every
-    row the steps compute) accumulates them on the device through the
-    steps and reads them back once, into ``last_stats``.
+    position while other slots are mid-decode or still empty. A finished
+    slot is freed and reused by the next pending request — admission
+    overwrites the entire ``[1, total]`` row, so no state can bleed
+    between consecutive occupants. Transfers are double-buffered: each
+    prefill worker's next shipment is dispatched (async) at admission
+    time, so it overlaps the decode steps that run before the next slot
+    frees; the wall-clock wait the overlap failed to hide is recorded in
+    ``_generate_slots.last_stats`` (the launcher prints it), with the
+    kept logit rows under ``keep_logits`` (see :func:`generate`). A
+    configuration whose decode step counts (``step_lib.decode_counters``:
+    the held-share expert layer's ``moe_held_pairs`` and
+    ``moe_max_expert_tokens``, over every row the steps compute)
+    accumulates them on the device through the steps and reads them back
+    once, into ``last_stats``.
+
+    Admission order is owned by :class:`repro.dist.fanin.AdmissionArbiter`.
+    The plain configuration (one worker, no ``paged``, no
+    ``prefill_meshes``) is one class, one shipment in flight and no
+    eviction: FIFO into the lowest free slot. The fan-in configuration
+    (``workers > 1``, ``paged`` or ``prefill_meshes``) has ``workers``
+    prefill workers, each on its own mesh when ``prefill_meshes`` gives
+    one per worker, priority classes (``priorities``), aging + hard
+    promotion and per-worker in-flight accounting; the engine *blocks on
+    the arbiter's chosen shipment* rather than admitting whichever worker
+    finishes first, so the token stream is replayable under permuted
+    worker completion order. There, when the table is full and the
+    pending request outranks a victim (or has hit the hard promotion
+    bound), ``evict`` preempts it recompute-style: the victim's slot is
+    freed, and the victim requeues with its already-emitted tokens
+    appended to its prompt and ``max_new`` reduced by them. Readmission
+    prefills the extended prompt at its exact length — the first
+    readmitted token comes from the prefill's last-position logits — so
+    the greedy continuation is bit-identical to an uncontended run (the
+    parity ``tests/test_serve_fanin.py`` pins). The fan-in configuration
+    is greedy-only (a sampled continuation across that recompute is not
+    replayable) and keeps no logits.
+
+    ``paged=True`` stores the slot table as a
+    :class:`repro.models.registry.PagedStateStore`: admission allocates
+    and ships only the pages covering the request's live positions, a
+    fresh page is allocated (host-side) whenever a slot decodes across a
+    page boundary, and each decode step runs the *unchanged* dense step
+    bracketed by the store's gather/scatter through the page table —
+    greedy tokens bit-match the unpaged path. The page size comes from
+    the tuned ``paged_attn`` registry point unless ``page_size`` pins
+    it; ``pool_pages`` bounds the shared pool (0 = fully backed), and
+    exhausting it is a loud error, never a stall. Long requests that an
+    unpaged horizon would refuse admit here — the horizon grows to the
+    next page multiple that fits the longest request.
 
     Returns tokens ``(B, max_new)``; greedy tokens are token-for-token
     identical to the whole-batch path (per-row attention independence —
     the property ``tests/test_serve_disagg.py`` pins on the 8-device
     mesh).
     """
+    fan_in = workers > 1 or paged or prefill_meshes is not None
+    if fan_in:
+        if keep_logits:
+            raise ValueError("keep_logits: the batch stream and the plain "
+                             "slot stream only (not workers > 1, paged "
+                             "or prefill_meshes)")
+        if temperature > 0:
+            raise ValueError(
+                "fan-in serving is greedy-only: an evicted request "
+                "re-prefills its emitted tokens on readmission, and a "
+                "sampled continuation across that recompute is not "
+                "replayable; use temperature=0 (the single-worker paths "
+                "support sampling)")
+        if evict not in fanin.EVICTION_POLICIES:
+            raise ValueError(f"unknown eviction policy {evict!r}; "
+                             f"expected one of {fanin.EVICTION_POLICIES}")
+        if workers < 1:
+            raise ValueError(
+                f"need at least one prefill worker, got {workers}")
+    else:
+        workers, evict, priorities = 1, "none", None
     b, s0 = prompts.shape
-    total = int(horizon) if horizon else s0 + max_new
     lens = np.asarray(prompt_lens, np.int32) if prompt_lens is not None \
         else np.full((b,), s0, np.int32)
-    _check_prompt_lens(cfg, lens, b, s0, max_new, total, paged=False)
+    if paged:
+        # the horizon never caps a paged table — it grows to the longest
+        # request (a request an unpaged horizon refuses admits here)
+        base = max(int(horizon), int((lens + max_new).max()))
+        P = int(page_size) or _default_page(base)
+        if P < 1:
+            raise ValueError(f"page size must be >= 1, got {P}")
+        total = -(-base // P) * P        # next page multiple that fits
+    else:
+        P = 0
+        total = int(horizon) if horizon else s0 + max_new
+    _check_prompt_lens(cfg, lens, b, s0, max_new, total, paged=paged)
     # fail before any compile: quantized storage refuses recurrent
     # caches; make_slot_admit_step re-checks for direct callers
     _require_slot_streaming(cfg)
     caps = registry.capabilities(cfg)
-    if cache_transfer not in collectives.CACHE_TRANSFERS:
-        raise ValueError(f"unknown cache_transfer {cache_transfer!r}; "
-                         f"expected one of {collectives.CACHE_TRANSFERS}")
+    prios = np.zeros((b,), np.int32) if priorities is None \
+        else np.asarray(priorities, np.int32)
+    if prios.shape != (b,):
+        raise ValueError(f"priorities shape {tuple(prios.shape)} does not "
+                         f"match the batch ({b},)")
     n_slots = int(slots) if slots else b
     if n_slots < 1:
         raise ValueError(f"slot table needs at least one slot, got {slots}")
+    if prefill_meshes is not None:
+        prefill_meshes = list(prefill_meshes)
+        if len(prefill_meshes) != workers:
+            raise ValueError(
+                f"{len(prefill_meshes)} prefill meshes for {workers} "
+                f"workers: fan-in needs one mesh per worker (or none)")
+    else:
+        prefill_meshes = [mesh] * workers
 
     with span(SERVE_GENERATE, requests=b, slots=n_slots):
         with span(SERVE_SETUP):
-            disagg = decode_mesh is not None
-            if disagg and mesh is None:
-                raise ValueError("disaggregated serving (decode_mesh=...) "
-                                 "needs a prefill mesh too")
-            if mesh is not None and rules is None:
-                rules = shd.PRESETS["serve_sp"]
-            if disagg and decode_rules is None:
-                decode_rules = shd.PRESETS["serve_decode"]
-            dec_mesh = decode_mesh if disagg else mesh
-            dec_rules = decode_rules if disagg else rules
-
-            prefill_fn = step_lib.make_prefill_step(cfg, act_transport)
-            dec_act = "bf16" if disagg and \
-                dec_rules is shd.PRESETS["serve_decode"] else act_transport
-            decode_fn = step_lib.make_decode_step(cfg, total, dec_act,
-                                                  kv_storage)
-
-            pre_ctx = shd.axis_rules(mesh, rules) if mesh is not None \
-                else contextlib.nullcontext()
-            dec_ctx = shd.axis_rules(dec_mesh, dec_rules) \
-                if dec_mesh is not None else contextlib.nullcontext()
-
-            slice_abs = transformer.abstract_cache(cfg, 1, total)
-            store_abs = transformer.abstract_cache(cfg, n_slots, total,
-                                                   kv_storage=kv_storage)
-
-            with pre_ctx:
-                params_pre = params
-                if mesh is not None:
-                    p_shard = shd.tree_shardings(
-                        transformer.abstract_params(cfg),
-                        transformer.param_axes(cfg), mesh, rules)
-                    params_pre = jax.device_put(params, p_shard)
-                prefill = jax.jit(prefill_fn)
-
-                def grow(c):
-                    return grow_cache(c, slice_abs)
-                # the program's name in a device trace: jit_grow_cache
-                grow.__name__ = "grow_cache"
-                grow = jax.jit(grow)
-
-            with dec_ctx:
-                c_shard = mover = None
-                params_dec = params_pre
-                if dec_mesh is not None:
-                    c_shard = shd.tree_shardings(
-                        store_abs,
-                        transformer.cache_axes(cfg, n_slots, total,
-                                               kv_storage=kv_storage),
-                        dec_mesh, dec_rules)
-                    if disagg:
-                        p_shard_dec = shd.tree_shardings(
-                            transformer.abstract_params(cfg),
-                            transformer.param_axes(cfg), dec_mesh, dec_rules)
-                        params_dec = jax.device_put(params, p_shard_dec)
-                        slice_dst = shd.tree_shardings(
-                            slice_abs, transformer.cache_axes(cfg, 1, total),
-                            dec_mesh, dec_rules)
-                        mover = make_cache_mover(cfg, 1, total, dec_mesh,
-                                                 dec_rules, cache_transfer,
-                                                 slice_dst)
+            store = registry.paged_state_store(
+                cfg, n_slots, total, kv_storage=kv_storage, page=P,
+                pool_pages=int(pool_pages)) if paged else None
+            # device-side counters the decode steps accumulate (the
+            # held-share expert layer's), read back once at the end
+            counters = step_lib.decode_counters(cfg)
+            progs = _Programs(
+                cfg, params, n_slots, total, mesh=mesh, rules=rules,
+                act_transport=act_transport, decode_mesh=decode_mesh,
+                decode_rules=decode_rules, cache_transfer=cache_transfer,
+                kv_storage=kv_storage, prefill_meshes=prefill_meshes,
+                paged_store=store, counters=counters is not None)
+            admit_transfer = "bf16" if progs.disagg else cache_transfer
+            if paged:
+                def admit_pages(cache, slc, page_idx):
+                    return store.admit_pages(cache, slc, page_idx,
+                                             transfer=admit_transfer)
+                admit = jax.jit(admit_pages, out_shardings=progs.c_shard)
+            else:
                 admit = jax.jit(make_slot_admit_step(
-                    cfg, n_slots, total,
-                    "bf16" if disagg else cache_transfer, kv_storage),
-                    out_shardings=c_shard)
-                # device-side counters the decode steps accumulate (the
-                # held-share expert layer's), read back once at the end
-                counters = step_lib.decode_counters(cfg)
-                dec_out = (None, c_shard) if counters is None \
-                    else (None, c_shard, None)
-                decode = jax.jit(decode_fn, out_shardings=dec_out) \
-                    if c_shard is not None else jax.jit(decode_fn)
+                    cfg, n_slots, total, admit_transfer, kv_storage),
+                    out_shardings=progs.c_shard)
+            with progs.dec_ctx:
+                cache = progs.init_cache()
 
-                def init_cache():
-                    return jax.tree.map(
-                        lambda s: jnp.zeros(s.shape, s.dtype), store_abs)
-                cache = jax.jit(init_cache, out_shardings=c_shard)()
-
-        # ---- host-side slot table + double-buffered prefetch ----------------
+        # ---- host side: arbiter queue, slot table, page table -----------
         key = jax.random.PRNGKey(seed)
+        arb = fanin.AdmissionArbiter(
+            workers=workers, classes=int(prios.max()) + 1 if b else 1)
+        prompt_of = [np.asarray(prompts[i, :lens[i]], np.int32)
+                     for i in range(b)]
+        reqs = [arb.submit(fanin.Request(rid=i, prompt=prompt_of[i],
+                                         max_new=int(max_new),
+                                         priority=int(prios[i])))
+                for i in range(b)]
         out_tokens = [[] for _ in range(b)]
         slot_req = [-1] * n_slots          # request id per slot, -1 = free
+        slot_occ: list = [None] * n_slots  # the arbiter's fanin.Occupant
         slot_tok = np.zeros((n_slots,), np.int32)
         slot_pos = np.zeros((n_slots,), np.int32)
         slot_keys: list = [None] * n_slots
-        next_req = 0
-        inflight: list = []                # at most one prefetched shipment
-        stats = {"admissions": 0, "transfer_wait_s": 0.0, "decode_steps": 0}
+        # rid -> (cache slice, first token, its logits, prompt length)
+        shipments = {}
+        pt = store.init_page_table() if paged else None
+        free_pages = deque(range(store.n_pool)) if paged else None
+        stats = {"admissions": 0, "evictions": 0, "requeues": 0,
+                 "decode_steps": 0, "transfer_wait_s": 0.0,
+                 "max_wait_passes": 0}
+        if paged:
+            stats["peak_live_pages"] = 0
         # per request, the (logits, row) each of its tokens was chosen from
         kept = [[] for _ in range(b)] if keep_logits else None
 
-        def start_prefetch():
-            """Prefill + ship the next pending request (async dispatch): the
-            wire transfer overlaps whatever decode steps run before the next
-            admission — the double buffer."""
-            nonlocal next_req
-            if next_req >= b or inflight:
-                return
-            i = next_req
-            next_req += 1
-            with span(SERVE_PREFILL, request=i):
-                with pre_ctx:
-                    if caps.row_state:
-                        # ring-buffer / recurrent state: pad tokens must
-                        # never enter the per-row state, so prefill the
-                        # request at its exact length (one compile per
-                        # distinct length) instead of masking a padded batch
-                        logits, c = prefill(params_pre, {
-                            "tokens": jnp.asarray(prompts[i:i + 1,
-                                                          :lens[i]])})
-                    else:
-                        logits, c = prefill(params_pre, {
-                            "tokens": jnp.asarray(prompts[i:i + 1]),
-                            "last_pos": jnp.asarray(lens[i:i + 1] - 1)})
-                    slc = grow(c)
-                    tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
-                if mover is not None:
-                    slc = mover(slc)
-                inflight.append((i, slc, tok0, logits))
+        def alloc_page() -> int:
+            if not free_pages:
+                raise RuntimeError(
+                    f"paged pool exhausted: all {store.n_pool} pages of "
+                    f"the {n_slots}-slot table are live; raise "
+                    f"--pool-pages (0 = fully backed: slots x "
+                    f"pages-per-row = {n_slots * store.pages_per_row}) or "
+                    f"lower --slots")
+            p = free_pages.popleft()
+            stats["peak_live_pages"] = max(stats["peak_live_pages"],
+                                           store.n_pool - len(free_pages))
+            return p
+
+        def ensure_page(s, pos):
+            """Allocate the page holding ``pos`` before the slot writes
+            it."""
+            pg = pos // P
+            if pg >= store.pages_per_row:
+                raise RuntimeError(
+                    f"slot {s} at position {pos} is past the {total}-"
+                    f"position paged horizon — engine accounting bug")
+            if pt[s, pg] < 0:
+                pt[s, pg] = alloc_page()
+
+        def free_row(s):
+            if paged:
+                for pg in np.nonzero(pt[s] >= 0)[0]:
+                    free_pages.append(int(pt[s, pg]))
+                pt[s, :] = -1
+            slot_req[s] = -1
+            slot_occ[s] = None
+
+        def dispatch():
+            """Prefill + ship the requests the arbiter hands to free
+            workers (async dispatch): each wire transfer overlaps whatever
+            decode steps run before its admission — the double buffer."""
+            for req in arb.assign():
+                i, w = req.rid, req.worker
+                plen = int(req.prompt.shape[0])
+                with span(SERVE_PREFILL, request=i):
+                    with progs.pre_ctx[w]:
+                        if caps.row_state or req.evictions:
+                            # ring-buffer / recurrent state: pad tokens
+                            # must never enter the per-row state, and a
+                            # readmission replays its emitted tokens, so
+                            # prefill at the exact length (one compile
+                            # per distinct length)
+                            logits, c = progs.prefill[w](
+                                progs.params_pre[w],
+                                {"tokens": jnp.asarray(req.prompt[None])})
+                        else:
+                            logits, c = progs.prefill[w](
+                                progs.params_pre[w], {
+                                    "tokens": jnp.asarray(prompts[i:i + 1]),
+                                    "last_pos": jnp.asarray(
+                                        lens[i:i + 1] - 1)})
+                        width = -(-plen // P) * P if paged else total
+                        slc = progs.grow(width)(c)
+                        tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
+                    if progs.disagg:
+                        slc = progs.mover(1, width)(slc)
+                    shipments[i] = (slc, tok0, logits, plen)
 
         def emit(i, t, slot):
             out_tokens[i].append(int(t))
             if len(out_tokens[i]) >= max_new:
-                slot_req[slot] = -1        # free the slot for reuse
+                free_row(slot)             # free the slot for reuse
 
-        def admit_next(slot):
+        def evict_slot(s):
+            i = slot_req[s]
+            arb.evicted(reqs[i])
+            # recompute preemption: requeue with the emitted tokens
+            # appended, budget reduced by them; aging restarts
+            reqs[i].prompt = np.concatenate(
+                [prompt_of[i], np.asarray(out_tokens[i], np.int32)])
+            reqs[i].max_new = max_new - len(out_tokens[i])
+            free_row(s)
+            arb.submit(reqs[i], requeue=True)
+            stats["evictions"] += 1
+            stats["requeues"] += 1
+
+        def admit_into(slot, req):
             nonlocal cache
-            if not inflight:
-                start_prefetch()
-            i, slc, tok0, logits0 = inflight.pop(0)
+            i = req.rid
+            slc, tok0, logits0, plen = shipments.pop(i)
             with span(SERVE_ADMIT, request=i):
                 with span(SERVE_TRANSFER_WAIT):
                     t0 = time.time()
+                    # the arbiter's choice, not the first shipment done:
                     # what the overlap failed to hide
                     jax.block_until_ready(slc)
                     stats["transfer_wait_s"] += time.time() - t0
-                with dec_ctx:
-                    cache = admit(cache, slc, jnp.asarray(slot, jnp.int32))
+                slot_occ[slot] = arb.admit(req)
+                stats["max_wait_passes"] = max(stats["max_wait_passes"],
+                                               req.skips)
+                with progs.dec_ctx:
+                    if paged:
+                        n_ship = -(-plen // P)
+                        idx = np.asarray([alloc_page()
+                                          for _ in range(n_ship)], np.int32)
+                        pt[slot, :n_ship] = idx
+                        cache = admit(cache, slc, jnp.asarray(idx))
+                    else:
+                        cache = admit(cache, slc,
+                                      jnp.asarray(slot, jnp.int32))
                 stats["admissions"] += 1
                 slot_req[slot] = i
-                slot_pos[slot] = lens[i]
+                slot_pos[slot] = plen
                 slot_tok[slot] = int(np.asarray(tok0)[0])
                 slot_keys[slot] = jax.random.fold_in(key, i)
                 if kept is not None:
                     kept[i].append((logits0, 0))
                 emit(i, slot_tok[slot], slot)  # the prefill token
-                start_prefetch()           # double buffer the next shipment
+                dispatch()                 # double buffer the next shipment
 
-        start_prefetch()
+        def refill():
+            """Admit in the arbiter's order until the queue or the table
+            runs out — a slot freed AT admission (max_new == 1: the
+            prefill token is the whole request) is refilled in the same
+            pass."""
+            dispatch()
+            while True:
+                req = arb.next_admission()
+                if req is None:
+                    return
+                s = next((j for j in range(n_slots) if slot_req[j] < 0),
+                         None)
+                if s is None:
+                    s = arb.pick_victim(slot_occ, evict, req)
+                    if s is None:
+                        return             # no justified victim: it ages
+                    evict_slot(s)
+                admit_into(s, req)
+
+        # ---- main loop: admit -> age -> decode -> sample -> emit ---------
+        passes = 0
+        limit = 1000 + 20 * b * (max_new + n_slots + arb.promotion_cycles)
         while True:
-            # keep admitting until the table is full or the queue drains — a
-            # slot freed AT admission (max_new == 1: the prefill token is the
-            # whole request) must be refilled in the same pass, or pending
-            # requests would be dropped when every slot reads free below
-            admitted = True
-            while admitted:
-                admitted = False
-                for s_ in range(n_slots):
-                    if slot_req[s_] < 0 and (inflight or next_req < b):
-                        admit_next(s_)
-                        admitted = True
+            passes += 1
+            if passes > limit:
+                raise RuntimeError(
+                    f"fan-in engine made no progress in {limit} passes "
+                    f"(queue={len(arb.queue)}, "
+                    f"occupied={sum(r >= 0 for r in slot_req)})")
+            refill()
+            arb.age()
             if all(r < 0 for r in slot_req):
-                break                      # nothing active, nothing pending
+                if not arb.queue:
+                    break                  # nothing active, nothing pending
+                continue
             with span(SERVE_DECODE, step=stats["decode_steps"]):
-                tok = jnp.asarray(slot_tok[:, None])
-                pos = jnp.asarray(slot_pos)
-                with dec_ctx:
+                batch = {"tokens": jnp.asarray(slot_tok[:, None]),
+                         "pos": jnp.asarray(slot_pos)}
+                if paged:
+                    for s_ in range(n_slots):
+                        if slot_req[s_] >= 0:
+                            ensure_page(s_, int(slot_pos[s_]))
+                    batch["page_table"] = jnp.asarray(pt)
+                with progs.dec_ctx:
                     if counters is None:
-                        logits, cache = decode(params_dec, cache,
-                                               {"tokens": tok, "pos": pos})
+                        logits, cache = progs.decode(progs.params_dec,
+                                                     cache, batch)
                     else:
-                        logits, cache, counters = decode(
-                            params_dec, cache, {"tokens": tok, "pos": pos},
-                            counters)
+                        logits, cache, counters = progs.decode(
+                            progs.params_dec, cache, batch, counters)
             stats["decode_steps"] += 1
             with span(SERVE_SAMPLE):
                 if temperature > 0:
@@ -767,10 +974,21 @@ StateStore` row write), and the slot decodes from the request's own
                         kept[i].append((logits, s_))
                     emit(i, nxt[s_], s_)
 
-        assert all(len(ts) == max_new for ts in out_tokens)
+        bad = [i for i in range(b) if len(out_tokens[i]) != max_new]
+        if bad:
+            raise RuntimeError(f"fan-in engine dropped requests {bad}: "
+                               f"emitted {[len(out_tokens[i]) for i in bad]} "
+                               f"of {max_new} tokens")
         if counters is not None:
             stats.update({k: int(v) for k, v in
                           jax.device_get(counters).items()})
+        if paged:
+            stats["page"] = P
+            stats["hbm_bytes_per_slot"] = (stats["peak_live_pages"]
+                                           * store.page_bytes()) // n_slots
+            dense = sum(int(np.prod(l.shape)) * l.dtype.itemsize
+                        for l in store.dense_abstract_state().values())
+            stats["dense_hbm_bytes_per_slot"] = dense // n_slots
         if kept is not None:
             host = {}                      # one device-to-host copy per array
 
@@ -782,420 +1000,6 @@ StateStore` row write), and the slot decodes from the request's own
         stats["logits"] = kept
         _generate_slots.last_stats = stats     # launcher reporting hook
         return np.asarray(out_tokens, np.int32)
-
-
-def _generate_fanin(cfg, params, prompts: np.ndarray, max_new: int,
-                    temperature: float, seed: int,
-                    prompt_lens: Optional[np.ndarray],
-                    mesh, rules, act_transport: str,
-                    decode_mesh, decode_rules,
-                    cache_transfer: str, kv_storage: str, slots: int,
-                    workers: int, evict: str, paged: bool, page_size: int,
-                    pool_pages: int, horizon: int,
-                    priorities: Optional[np.ndarray], prefill_meshes):
-    """Multi-prefill-worker fan-in with slot preemption and an optional
-    paged slot cache.
-
-    ``workers`` prefill workers — each the slot streamer's prefill +
-    double-buffered mover, on its own mesh when ``prefill_meshes`` gives
-    one per worker — feed ONE decode slot table. Admission order is
-    owned by :class:`repro.dist.fanin.AdmissionArbiter` (FIFO with
-    priority classes, aging + hard promotion, per-worker in-flight
-    accounting); the engine *blocks on the arbiter's chosen shipment*
-    rather than admitting whichever worker finishes first, so the token
-    stream is replayable under permuted worker completion order.
-
-    Preemption is recompute-style: when the table is full and the
-    pending request outranks a victim (or has hit the hard promotion
-    bound), the victim's slot is freed, and the victim requeues with its
-    already-emitted tokens appended to its prompt and ``max_new``
-    reduced by them. Readmission prefills the extended prompt at its
-    exact length — the first readmitted token comes from the prefill's
-    last-position logits — so the greedy continuation is bit-identical
-    to an uncontended run (the parity ``tests/test_serve_fanin.py``
-    pins).
-
-    ``paged=True`` stores the slot table as a
-    :class:`repro.models.registry.PagedStateStore`: admission allocates
-    and ships only the pages covering the request's live positions, a
-    fresh page is allocated (host-side) whenever a slot decodes across a
-    page boundary, and each decode step runs the *unchanged* dense step
-    bracketed by the store's gather/scatter through the page table —
-    greedy tokens bit-match the unpaged path. The page size comes from
-    the tuned ``paged_attn`` registry point unless ``page_size`` pins
-    it; ``pool_pages`` bounds the shared pool (0 = fully backed), and
-    exhausting it is a loud error, never a stall. Long requests that an
-    unpaged horizon would refuse admit here — the horizon grows to the
-    next page multiple that fits the longest request.
-
-    Greedy only: an evicted request re-prefills its emitted tokens, and
-    a sampled continuation across that recompute is not replayable.
-    """
-    if temperature > 0:
-        raise ValueError(
-            "fan-in serving is greedy-only: an evicted request re-prefills "
-            "its emitted tokens on readmission, and a sampled continuation "
-            "across that recompute is not replayable; use temperature=0 "
-            "(the single-worker paths support sampling)")
-    if evict not in fanin.EVICTION_POLICIES:
-        raise ValueError(f"unknown eviction policy {evict!r}; "
-                         f"expected one of {fanin.EVICTION_POLICIES}")
-    if workers < 1:
-        raise ValueError(f"need at least one prefill worker, got {workers}")
-    if cache_transfer not in collectives.CACHE_TRANSFERS:
-        raise ValueError(f"unknown cache_transfer {cache_transfer!r}; "
-                         f"expected one of {collectives.CACHE_TRANSFERS}")
-    b, s0 = prompts.shape
-    lens = np.asarray(prompt_lens, np.int32) if prompt_lens is not None \
-        else np.full((b,), s0, np.int32)
-    _require_slot_streaming(cfg)
-    caps = registry.capabilities(cfg)
-    prios = np.zeros((b,), np.int32) if priorities is None \
-        else np.asarray(priorities, np.int32)
-    if prios.shape != (b,):
-        raise ValueError(f"priorities shape {tuple(prios.shape)} does not "
-                         f"match the batch ({b},)")
-    classes = int(prios.max()) + 1 if b else 1
-    n_slots = int(slots) if slots else b
-    if n_slots < 1:
-        raise ValueError(f"slot table needs at least one slot, got {slots}")
-
-    # ---- horizon / page sizing -----------------------------------------
-    if paged:
-        # the horizon never caps a paged table — it grows to the longest
-        # request (that is the bugfix's "--paged admits it" arm)
-        base = max(int(horizon), int((lens + max_new).max()))
-        P = int(page_size) or _default_page(base)
-        if P < 1:
-            raise ValueError(f"page size must be >= 1, got {P}")
-        total = -(-base // P) * P        # next page multiple that fits
-        _check_prompt_lens(cfg, lens, b, s0, max_new, total, paged=True)
-    else:
-        P = 0
-        total = int(horizon) if horizon else s0 + max_new
-        _check_prompt_lens(cfg, lens, b, s0, max_new, total, paged=False)
-
-    disagg = decode_mesh is not None
-    if prefill_meshes is not None:
-        prefill_meshes = list(prefill_meshes)
-        if len(prefill_meshes) != workers:
-            raise ValueError(
-                f"{len(prefill_meshes)} prefill meshes for {workers} "
-                f"workers: fan-in needs one mesh per worker (or none)")
-        if mesh is None:
-            mesh = prefill_meshes[0]
-    else:
-        prefill_meshes = [mesh] * workers
-    if disagg and mesh is None:
-        raise ValueError("disaggregated serving (decode_mesh=...) needs a "
-                         "prefill mesh too")
-    if mesh is not None and rules is None:
-        rules = shd.PRESETS["serve_sp"]
-    if disagg and decode_rules is None:
-        decode_rules = shd.PRESETS["serve_decode"]
-    dec_mesh = decode_mesh if disagg else mesh
-    dec_rules = decode_rules if disagg else rules
-
-    prefill_fn = step_lib.make_prefill_step(cfg, act_transport)
-    dec_act = "bf16" if disagg and dec_rules is shd.PRESETS["serve_decode"] \
-        else act_transport
-    decode_fn = step_lib.make_decode_step(cfg, total, dec_act, kv_storage)
-
-    pre_ctx = [shd.axis_rules(m, rules) if m is not None
-               else contextlib.nullcontext() for m in prefill_meshes]
-    dec_ctx = shd.axis_rules(dec_mesh, dec_rules) if dec_mesh is not None \
-        else contextlib.nullcontext()
-
-    # ---- params: one placement per distinct prefill mesh ----------------
-    params_pre = [params] * workers
-    placed = {}
-    for w, m in enumerate(prefill_meshes):
-        if m is None:
-            continue
-        if id(m) not in placed:
-            p_shard = shd.tree_shardings(transformer.abstract_params(cfg),
-                                         transformer.param_axes(cfg),
-                                         m, rules)
-            placed[id(m)] = jax.device_put(params, p_shard)
-        params_pre[w] = placed[id(m)]
-    # One jit per DISTINCT worker mesh: ``constrain`` bakes the trace-time
-    # mesh into the jaxpr and jit reuses traces by aval alone, so a shared
-    # jit would replay worker 0's sharding constraints on worker 1's
-    # devices (incompatible-devices error on the first cross-worker call).
-    _prefill_jits = {}
-
-    def prefill_for(w):
-        k = id(prefill_meshes[w])
-        if k not in _prefill_jits:
-            # a DISTINCT callable per mesh, not just a distinct jit
-            # wrapper: pjit's trace cache is keyed on the wrapped
-            # function object, so jitting the same fn twice would still
-            # share the first worker's jaxpr
-            _prefill_jits[k] = jax.jit(
-                lambda p, batch, _f=prefill_fn: _f(p, batch))
-        return _prefill_jits[k]
-
-    # per-slice-width jits: fit (pre side) and mover (cross-mesh); paged
-    # admissions ship ceil(len / page) pages, so the width varies
-    fit_jits, mover_jits = {}, {}
-
-    def fit(width):
-        if width not in fit_jits:
-            abs_w = transformer.abstract_cache(cfg, 1, width)
-            fit_jits[width] = jax.jit(lambda c, a=abs_w: fit_cache(c, a))
-        return fit_jits[width]
-
-    def mover(width):
-        if width not in mover_jits:
-            abs_w = transformer.abstract_cache(cfg, 1, width)
-            dst = shd.tree_shardings(abs_w,
-                                     transformer.cache_axes(cfg, 1, width),
-                                     dec_mesh, dec_rules)
-            mover_jits[width] = make_cache_mover(
-                cfg, 1, width, dec_mesh, dec_rules, cache_transfer, dst)
-        return mover_jits[width]
-
-    # ---- decode-side programs: slot table (dense or paged) --------------
-    with dec_ctx:
-        c_shard = None
-        params_dec = params_pre[0]
-        if disagg:
-            p_shard_dec = shd.tree_shardings(
-                transformer.abstract_params(cfg),
-                transformer.param_axes(cfg), dec_mesh, dec_rules)
-            params_dec = jax.device_put(params, p_shard_dec)
-        admit_transfer = "bf16" if disagg else cache_transfer
-        if paged:
-            store = registry.paged_state_store(
-                cfg, n_slots, total, kv_storage=kv_storage, page=P,
-                pool_pages=int(pool_pages))
-            store_abs = store.abstract_state()
-            if dec_mesh is not None:
-                c_shard = shd.tree_shardings(store_abs, store.state_axes(),
-                                             dec_mesh, dec_rules)
-
-            def admit_fn(cache, slc, page_idx):
-                return store.admit_pages(cache, slc, page_idx,
-                                         transfer=admit_transfer)
-
-            def paged_step(p, pool, pt, batch):
-                dense = store.gather_dense(pool, pt)
-                logits, dense = decode_fn(p, dense, batch)
-                return logits, store.scatter_dense(pool, dense, pt)
-
-            admit = jax.jit(admit_fn, out_shardings=c_shard)
-            decode = jax.jit(paged_step, out_shardings=(None, c_shard)) \
-                if c_shard is not None else jax.jit(paged_step)
-        else:
-            store = registry.state_store(cfg, n_slots, total,
-                                         kv_storage=kv_storage)
-            store_abs = transformer.abstract_cache(cfg, n_slots, total,
-                                                   kv_storage=kv_storage)
-            if dec_mesh is not None:
-                c_shard = shd.tree_shardings(
-                    store_abs,
-                    transformer.cache_axes(cfg, n_slots, total,
-                                           kv_storage=kv_storage),
-                    dec_mesh, dec_rules)
-            admit = jax.jit(make_slot_admit_step(
-                cfg, n_slots, total, admit_transfer, kv_storage),
-                out_shardings=c_shard)
-            decode = jax.jit(decode_fn, out_shardings=(None, c_shard)) \
-                if c_shard is not None else jax.jit(decode_fn)
-        cache = jax.jit(lambda: jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), store_abs),
-            out_shardings=c_shard)()
-
-    # ---- host state: queue, slot table, page table ----------------------
-    arb = fanin.AdmissionArbiter(workers=workers, classes=classes)
-    base_prompts = [np.asarray(prompts[i, :lens[i]], np.int32).copy()
-                    for i in range(b)]
-    for i in range(b):
-        arb.submit(fanin.Request(rid=i, prompt=base_prompts[i],
-                                 max_new=int(max_new),
-                                 priority=int(prios[i])))
-    out_tokens = [[] for _ in range(b)]
-    remaining = np.full((b,), max_new, np.int64)
-    slot_occ: list = [None] * n_slots           # fanin.Occupant or None
-    slot_reqobj: list = [None] * n_slots        # fanin.Request or None
-    slot_tok = np.zeros((n_slots,), np.int32)
-    slot_pos = np.zeros((n_slots,), np.int32)
-    shipments = {}                              # rid -> (slc, tok0, length)
-    pt = store.init_page_table() if paged else None
-    free_pages = deque(range(store.n_pool)) if paged else None
-    stats = {"admissions": 0, "evictions": 0, "requeues": 0,
-             "decode_steps": 0, "transfer_wait_s": 0.0,
-             "max_wait_passes": 0, "peak_live_pages": 0}
-
-    def alloc_page() -> int:
-        if not free_pages:
-            raise RuntimeError(
-                f"paged pool exhausted: all {store.n_pool} pages of the "
-                f"{n_slots}-slot table are live; raise --pool-pages "
-                f"(0 = fully backed: slots x pages-per-row = "
-                f"{n_slots * store.pages_per_row}) or lower --slots")
-        p = free_pages.popleft()
-        stats["peak_live_pages"] = max(stats["peak_live_pages"],
-                                       store.n_pool - len(free_pages))
-        return p
-
-    def free_row(s):
-        if paged:
-            for pg in np.nonzero(pt[s] >= 0)[0]:
-                free_pages.append(int(pt[s, pg]))
-            pt[s, :] = -1
-        slot_occ[s] = None
-        slot_reqobj[s] = None
-
-    def ensure_page(s, pos):
-        """Allocate the page holding ``pos`` before the slot writes it."""
-        pg = pos // P
-        if pg >= store.pages_per_row:
-            raise RuntimeError(
-                f"slot {s} at position {pos} is past the {total}-position "
-                f"paged horizon — engine accounting bug")
-        if pt[s, pg] < 0:
-            pt[s, pg] = alloc_page()
-
-    def dispatch(req):
-        """Prefill + ship one assigned request on its worker (async): the
-        wire transfer overlaps decode steps until the arbiter admits it."""
-        plen = int(req.prompt.shape[0])
-        w = req.worker
-        with pre_ctx[w]:
-            if req.evictions == 0 and not caps.row_state and plen <= s0:
-                # fresh admission: padded [1, S0] prefill with a last
-                # position — the same program for every fresh request
-                toks = np.zeros((1, s0), np.int32)
-                toks[0, :plen] = req.prompt
-                logits, c = prefill_for(w)(params_pre[w], {
-                    "tokens": jnp.asarray(toks),
-                    "last_pos": jnp.asarray([plen - 1])})
-            else:
-                # readmission (or row_state): exact-length prefill of the
-                # extended prompt — pad tokens must never enter row state,
-                # and the recompute must replay the emitted continuation
-                logits, c = prefill_for(w)(params_pre[w], {
-                    "tokens": jnp.asarray(req.prompt[None, :])})
-            width = -(-plen // P) * P if paged else total
-            slc = fit(width)(c)
-            tok0 = jnp.argmax(logits, -1).astype(jnp.int32)
-        if disagg:
-            slc = mover(width)(slc)
-        shipments[req.rid] = (slc, tok0, plen)
-
-    def emit(i, t, s):
-        out_tokens[i].append(int(t))
-        remaining[i] -= 1
-        if remaining[i] <= 0:
-            free_row(s)
-
-    def evict_slot(s):
-        req = slot_reqobj[s]
-        arb.evicted(req)
-        # recompute preemption: requeue with the emitted tokens appended,
-        # budget reduced by them; aging restarts for the new occupancy
-        req.prompt = np.concatenate(
-            [base_prompts[req.rid],
-             np.asarray(out_tokens[req.rid], np.int32)])
-        req.max_new = int(remaining[req.rid])
-        free_row(s)
-        arb.submit(req, requeue=True)
-        stats["evictions"] += 1
-        stats["requeues"] += 1
-
-    def admit_into(s, req):
-        nonlocal cache
-        slc, tok0, plen = shipments.pop(req.rid)
-        t0 = time.time()
-        jax.block_until_ready(slc)   # the arbiter's choice, NOT first-done
-        stats["transfer_wait_s"] += time.time() - t0
-        occ = arb.admit(req)
-        stats["max_wait_passes"] = max(stats["max_wait_passes"], req.skips)
-        with dec_ctx:
-            if paged:
-                n_ship = -(-plen // P)
-                idx = np.asarray([alloc_page() for _ in range(n_ship)],
-                                 np.int32)
-                pt[s, :n_ship] = idx
-                cache = admit(cache, slc, jnp.asarray(idx))
-            else:
-                cache = admit(cache, slc, jnp.asarray(s, jnp.int32))
-        stats["admissions"] += 1
-        slot_occ[s] = occ
-        slot_reqobj[s] = req
-        slot_pos[s] = plen
-        slot_tok[s] = int(np.asarray(tok0)[0])
-        emit(req.rid, slot_tok[s], s)           # the prefill token
-
-    def try_admissions():
-        while True:
-            req = arb.next_admission()
-            if req is None:
-                return
-            s = next((i for i in range(n_slots) if slot_occ[i] is None),
-                     None)
-            if s is None:
-                s = arb.pick_victim(slot_occ, evict, req)
-                if s is None:
-                    return              # no justified victim: age in queue
-                evict_slot(s)
-            admit_into(s, req)
-
-    # ---- main loop: assign -> admit -> age -> decode --------------------
-    passes = 0
-    limit = 1000 + 20 * b * (max_new + n_slots + arb.promotion_cycles)
-    while True:
-        passes += 1
-        if passes > limit:
-            raise RuntimeError(
-                f"fan-in engine made no progress in {limit} passes "
-                f"(queue={len(arb.queue)}, "
-                f"occupied={sum(o is not None for o in slot_occ)})")
-        for req in arb.assign():
-            dispatch(req)
-        try_admissions()
-        arb.age()
-        if all(o is None for o in slot_occ):
-            if not arb.queue:
-                break
-            continue
-        if paged:
-            for s in range(n_slots):
-                if slot_occ[s] is not None:
-                    ensure_page(s, int(slot_pos[s]))
-        tok = jnp.asarray(slot_tok[:, None])
-        pos = jnp.asarray(slot_pos)
-        with dec_ctx:
-            if paged:
-                logits, cache = decode(params_dec, cache, jnp.asarray(pt),
-                                       {"tokens": tok, "pos": pos})
-            else:
-                logits, cache = decode(params_dec, cache,
-                                       {"tokens": tok, "pos": pos})
-        stats["decode_steps"] += 1
-        nxt = np.asarray(jnp.argmax(logits, -1), np.int32)
-        for s in range(n_slots):
-            if slot_occ[s] is None:
-                continue
-            slot_tok[s] = int(nxt[s])
-            slot_pos[s] += 1
-            emit(slot_reqobj[s].rid, int(nxt[s]), s)
-
-    bad = [i for i in range(b) if len(out_tokens[i]) != max_new]
-    if bad:
-        raise RuntimeError(f"fan-in engine dropped requests {bad}: "
-                           f"emitted {[len(out_tokens[i]) for i in bad]} "
-                           f"of {max_new} tokens")
-    if paged:
-        stats["page"] = P
-        stats["hbm_bytes_per_slot"] = (stats["peak_live_pages"]
-                                       * store.page_bytes()) // n_slots
-        dense = sum(int(np.prod(l.shape)) * l.dtype.itemsize
-                    for l in store.dense_abstract_state().values())
-        stats["dense_hbm_bytes_per_slot"] = dense // n_slots
-    _generate_fanin.last_stats = stats          # launcher reporting hook
-    return np.asarray(out_tokens, np.int32)
 
 
 def _pick_tp(n_devices: int, cfg) -> int:
@@ -1641,13 +1445,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "batch via slot admission, transfers "
                          "double-buffered behind decode steps")
     ap.add_argument("--slots", type=int, default=0,
-                    help="slot-table size for --stream slots (0 = one "
+                    help="slot-table size of the slot engine (0 = one "
                          "slot per request; smaller forces slot reuse)")
     ap.add_argument("--workers", type=int, default=1,
                     help="prefill fan-in: N independent prefill workers "
                          "feeding one decode slot table through the "
-                         "admission arbiter (>1, or --paged, routes "
-                         "serving through the fan-in engine; greedy only)")
+                         "admission arbiter (>1, or --paged, selects the "
+                         "slot engine's fan-in configuration; greedy only)")
     ap.add_argument("--evict", default="oldest",
                     choices=list(fanin.EVICTION_POLICIES),
                     help="slot preemption policy when the table is full "
@@ -1772,25 +1576,27 @@ def main(argv=None) -> None:
           + (f" lens={lens.tolist()}" if lens is not None else ""))
     print(f"[serve] generated {n_tok} tokens in {dt:.2f}s "
           f"({n_tok/dt:.1f} tok/s incl. compile)")
-    if fan_in:
-        st = _generate_fanin.last_stats
-        print(f"[serve] fan-in: workers={args.workers} evict={args.evict} "
-              f"admissions={st['admissions']} evictions={st['evictions']} "
-              f"requeues={st['requeues']} decode_steps={st['decode_steps']} "
-              f"transfer_wait_s={st['transfer_wait_s']:.3f} "
-              f"max_wait_passes={st['max_wait_passes']}")
+    if fan_in or args.stream == "slots":
+        st = _generate_slots.last_stats
+        if fan_in:
+            print(f"[serve] fan-in: workers={args.workers} "
+                  f"evict={args.evict} admissions={st['admissions']} "
+                  f"evictions={st['evictions']} requeues={st['requeues']} "
+                  f"decode_steps={st['decode_steps']} "
+                  f"transfer_wait_s={st['transfer_wait_s']:.3f} "
+                  f"max_wait_passes={st['max_wait_passes']}")
+        else:
+            print(f"[serve] slot stream: admissions={st['admissions']} "
+                  f"decode_steps={st['decode_steps']} "
+                  f"transfer_wait_s={st['transfer_wait_s']:.3f} "
+                  "(wire time the double buffer failed to hide behind "
+                  "decode)")
         if args.paged:
             print(f"[serve] paged: page={st['page']} "
                   f"peak_live_pages={st['peak_live_pages']} "
                   f"hbm_bytes_per_slot={st['hbm_bytes_per_slot']} "
                   f"(dense pad-to-horizon "
                   f"{st['dense_hbm_bytes_per_slot']})")
-    elif args.stream == "slots":
-        st = _generate_slots.last_stats
-        print(f"[serve] slot stream: admissions={st['admissions']} "
-              f"decode_steps={st['decode_steps']} "
-              f"transfer_wait_s={st['transfer_wait_s']:.3f} "
-              "(wire time the double buffer failed to hide behind decode)")
     print("[serve] sample:", out[0][:10])
 
 

@@ -364,7 +364,9 @@ class TestSlotStreaming:
 
     def test_slot_reuse_no_cross_request_bleed(self, dense):
         """slots=1 serializes every request through ONE slot row — each
-        admission must fully overwrite the previous occupant."""
+        admission must fully overwrite the previous occupant. The plain
+        slot stream never preempts: every request is admitted once."""
+        from repro.launch.serve import _generate_slots
         cfg, params = dense
         prompts = _prompts(cfg, 4, 10, seed=23)
         lens = np.array([4, 10, 7, 9], np.int32)
@@ -373,6 +375,9 @@ class TestSlotStreaming:
             slot = generate(cfg, params, prompts, max_new=5,
                             prompt_lens=lens, stream="slots", slots=n_slots)
             assert (batch == slot).all(), (n_slots, batch, slot)
+            st = _generate_slots.last_stats
+            assert st["admissions"] == len(prompts)
+            assert st["evictions"] == st["requeues"] == 0
 
     def test_slot_stream_uniform_and_quantized_pipeline(self, dense):
         cfg, params = dense

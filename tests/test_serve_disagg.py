@@ -371,7 +371,7 @@ class TestFanInOnMesh:
                              slots=3, evict="priority", paged=True,
                              priorities=(np.arange(8) % 2).astype(np.int32))
         assert (out == golden).all(), (out, golden)
-        st = serve._generate_fanin.last_stats
+        st = serve._generate_slots.last_stats
         assert st["evictions"] > 0
         assert st["hbm_bytes_per_slot"] < st["dense_hbm_bytes_per_slot"]
 
@@ -388,4 +388,4 @@ class TestFanInOnMesh:
                              workers=2, slots=3, evict="oldest",
                              paged=True)
         assert (out == golden).all(), (out, golden)
-        assert serve._generate_fanin.last_stats["admissions"] >= 8
+        assert serve._generate_slots.last_stats["admissions"] >= 8

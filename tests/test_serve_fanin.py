@@ -204,7 +204,7 @@ class TestFanInEngine:
         out = serve.generate(cfg, params, prompts, max_new=8,
                              prompt_lens=lens, workers=2)
         assert (out == golden).all(), (out, golden)
-        st_ = serve._generate_fanin.last_stats
+        st_ = serve._generate_slots.last_stats
         assert st_["admissions"] == 4 and st_["evictions"] == 0
 
     def test_replay_is_deterministic(self, setup):
@@ -214,11 +214,11 @@ class TestFanInEngine:
         a = serve.generate(cfg, params, prompts, max_new=8,
                            prompt_lens=lens, workers=2, slots=2,
                            evict="oldest")
-        sa = dict(serve._generate_fanin.last_stats)
+        sa = dict(serve._generate_slots.last_stats)
         b = serve.generate(cfg, params, prompts, max_new=8,
                            prompt_lens=lens, workers=2, slots=2,
                            evict="oldest")
-        sb = dict(serve._generate_fanin.last_stats)
+        sb = dict(serve._generate_slots.last_stats)
         sa.pop("transfer_wait_s")          # the one wall-clock stat
         sb.pop("transfer_wait_s")
         assert (a == b).all() and sa == sb
@@ -240,7 +240,7 @@ class TestFanInEngine:
                              evict="priority",
                              priorities=np.array([1, 1, 0, 0], np.int32))
         assert (out == golden).all(), (out, golden)
-        st_ = serve._generate_fanin.last_stats
+        st_ = serve._generate_slots.last_stats
         assert st_["evictions"] > 0 and st_["requeues"] > 0
 
     def test_promotion_driven_oldest_eviction_matches(self, setup):
@@ -267,7 +267,7 @@ class TestPagedEngine:
                              prompt_lens=lens, workers=2, paged=True,
                              page_size=page_size)
         assert (out == golden).all(), (out, golden)
-        st_ = serve._generate_fanin.last_stats
+        st_ = serve._generate_slots.last_stats
         assert st_["page"] >= 1 and st_["peak_live_pages"] >= 1
         assert st_["hbm_bytes_per_slot"] \
             < st_["dense_hbm_bytes_per_slot"]
@@ -286,7 +286,7 @@ class TestPagedEngine:
                              kv_storage="int8",
                              priorities=np.array([1, 1, 0, 0], np.int32))
         assert (out == base).all(), (out, base)
-        assert serve._generate_fanin.last_stats["evictions"] > 0
+        assert serve._generate_slots.last_stats["evictions"] > 0
 
     def test_long_request_refused_unpaged_admitted_paged(self, setup):
         """The bugfix, both arms: a request past the unpaged horizon is

@@ -155,13 +155,22 @@ def test_cycle_emits_its_three_phases(tmp_path):
     assert any(inside(c, act) for c in named(tr, spans.TABLE_COMMIT))
 
 
-def test_slot_engine_emits_a_decode_span_a_step(tmp_path):
+# the plain slot stream, and the slot engine's fan-in configuration on one
+# device: two prefill workers into a paged table whose one page holds any
+# prompt of the tiny traffic
+SLOT_CONFIGS = {"plain": {},
+                "fan_in": {"workers": 2, "paged": True, "page_size": 16}}
+
+
+@pytest.mark.parametrize("config", sorted(SLOT_CONFIGS))
+def test_slot_engine_emits_a_decode_span_a_step(tmp_path, config):
     from bench.drivers import serving
     from bench.tests.serving_tiny import tiny
     cfg, tr_ = tiny("decode", requests=5, slots=2, max_new=4,
                     check_requests=2)
     d = serving.Driver(cfg, tr_, 11, lambda s: None)
     d.setup()
+    d.kwargs.update(SLOT_CONFIGS[config])
     with traced(tmp_path) as box:
         d.step()
     stats = d.serve._generate_slots.last_stats
@@ -173,12 +182,17 @@ def test_slot_engine_emits_a_decode_span_a_step(tmp_path):
     assert len(decodes) == stats["decode_steps"] > 0
     assert len(named(tr, spans.SERVE_SAMPLE)) == stats["decode_steps"]
     assert len(named(tr, spans.SERVE_EMIT)) == stats["decode_steps"]
+    # an evicted request is prefilled and admitted again; the plain slot
+    # stream never evicts
+    if config == "plain":
+        assert stats["evictions"] == 0
     admits = named(tr, spans.SERVE_ADMIT)
-    assert len(admits) == stats["admissions"] == tr_["requests"]
+    assert len(admits) == stats["admissions"] == \
+        tr_["requests"] + stats["evictions"]
     waits = named(tr, spans.SERVE_TRANSFER_WAIT)
     assert [sum(inside(w, a) for w in waits) for a in admits] == \
         [1] * len(admits)
-    assert len(named(tr, spans.SERVE_PREFILL)) == tr_["requests"]
+    assert len(named(tr, spans.SERVE_PREFILL)) == stats["admissions"]
     every = decodes + admits + named(tr, spans.SERVE_PREFILL)
     assert all(inside(e, gen) for e in every)
 
@@ -199,7 +213,17 @@ def test_compaction_programs_have_stable_names():
     assert "module @jit_compact_filter " in filt.as_text()
 
 
-def test_slot_engine_programs_have_stable_names(monkeypatch):
+# what each configuration jits, once a call: the paged table's decode step
+# wraps the unchanged dense one in its page-table gather and scatter
+SLOT_PROGRAMS = {
+    "plain": ["admit", "decode_step", "grow_cache", "init_cache",
+              "prefill_step"],
+    "fan_in": ["admit_pages", "grow_cache", "init_cache",
+               "paged_decode_step", "prefill_step"]}
+
+
+@pytest.mark.parametrize("config", sorted(SLOT_CONFIGS))
+def test_slot_engine_programs_have_stable_names(monkeypatch, config):
     """Every program the slot engine jits is a named function, so a
     device trace names it ``jit_<name>`` (a lambda or a partial would
     read ``jit__lambda`` or ``jit__unknown``)."""
@@ -209,6 +233,7 @@ def test_slot_engine_programs_have_stable_names(monkeypatch):
                     check_requests=2)
     d = serving.Driver(cfg, tr_, 5, lambda s: None)
     d.setup()
+    d.kwargs.update(SLOT_CONFIGS[config])
     names = []
     real = jax.jit
 
@@ -217,8 +242,7 @@ def test_slot_engine_programs_have_stable_names(monkeypatch):
         return real(fun, *args, **kw)
     monkeypatch.setattr(jax, "jit", jit)
     d.step()
-    assert sorted(names) == ["admit", "decode_step", "grow_cache",
-                             "init_cache", "prefill_step"]
+    assert sorted(names) == SLOT_PROGRAMS[config]
 
 
 def test_span_names_are_spelled_in_one_place():
